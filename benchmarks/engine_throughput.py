@@ -12,13 +12,12 @@ exactly what the probe-kernel work cannot recover from a host-bound loop.
 data-parallel meshes of 1/2/4/8 simulated host devices (one subprocess per
 device count — the device count is fixed at process start) and emits
 ``BENCH_serve_scaling.json`` — throughput plus per-request latency
-p50/p95/p99 — so the perf trajectory accumulates per PR.  ``--overlap on``
-serves through the double-buffered pipeline (``serve(overlap=True)``): one
-blocking snapshot read per chunk boundary instead of one sync per
-host-facing scalar, which is exactly the host overhead the sync sweep's
-scaling cliff is made of.  On one physical CPU the simulated sweep
-measures sharding/dispatch overhead, not real speedup; on real chips the
-same harness measures both.
+p50/p95/p99.  ``--overlap on`` serves through the double-buffered pipeline
+(``serve(overlap=True)``): one blocking snapshot read per chunk boundary
+instead of one sync per host-facing scalar.  The sweep runs on the CPU
+only: every child is started with ``JAX_PLATFORMS=cpu``, so it never
+contends for an accelerator, and its numbers are sharding/dispatch
+overheads of XLA's CPU backend, not a device measurement.
 
 ``--cache {ring,paged,both}`` runs the mixed-exit-length serving workload
 (temperature sampling — sequences exit via a naturally sampled </think> at
@@ -76,6 +75,7 @@ from repro.models import Model
 from repro.serving.cache import CacheConfig, page_align
 from repro.serving.engine import EngineConfig, ReasoningEngine
 from repro.serving.sampler import SamplerConfig
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def write_json(path: str, rec: dict) -> None:
@@ -512,7 +512,7 @@ def run_scaling_sweep(args) -> dict:
     def child(n, overlap_mode):
         env = dict(os.environ)
         env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n}"
-        env.setdefault("JAX_PLATFORMS", "cpu")
+        env["JAX_PLATFORMS"] = "cpu"
         env["PYTHONPATH"] = os.pathsep.join(
             [os.path.join(os.path.dirname(__file__), "..", "src")]
             + [p for p in env.get("PYTHONPATH", "").split(os.pathsep) if p]
@@ -576,6 +576,7 @@ def run_scaling_sweep(args) -> dict:
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--budget", type=int, default=96)

@@ -141,6 +141,9 @@ def markdown_table(mesh: str = "pod16x16") -> str:
 
 
 if __name__ == "__main__":
+    from repro.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     rows: list = []
     print(json.dumps(run(rows), indent=2))
     print(markdown_table())

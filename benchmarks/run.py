@@ -46,6 +46,9 @@ ART = os.path.join(os.path.dirname(__file__), "..", "artifacts")
 
 
 def main() -> None:
+    from repro.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None,
                     help="comma-separated module list")

@@ -6,7 +6,8 @@ ProxyExecutor program family (``executor.PROGRAM_FAMILIES``) is built over
 the full key matrix
 
     monitor tier {self, proxy} x cache kind {ring, paged}
-    x decode-attention impl {gather, xla, pallas(interpret on CPU)}
+    x decode-attention impl {gather, xla, pallas (interpret mode, asked
+      for through ``Model.interpret``: the audit runs on the CPU)}
     x delta regime {exit-at-first-eval, run-to-budget} for the monitored
       families (chunk / shadow / serve_step — delta is a traced constant)
 
@@ -73,6 +74,11 @@ _DONATION_CELLS = {
 }
 
 
+#: host-sync primitives whose names do not say "callback" (jax.debug.print
+#: binds ``debug_print``)
+_HOST_SYNC_PRIMITIVES = ("debug_print", "infeed", "outfeed")
+
+
 def _i32(shape=()):
     return jax.ShapeDtypeStruct(shape, jnp.int32)
 
@@ -104,7 +110,7 @@ def scan_jaxpr(jaxpr, where: str) -> list:
         seen.add(id(jx))
         for eqn in jx.eqns:
             name = eqn.primitive.name
-            if "callback" in name or name in ("infeed", "outfeed"):
+            if "callback" in name or name in _HOST_SYNC_PRIMITIVES:
                 out.append(Violation(
                     "lowered", where, "sync-point",
                     f"jaxpr contains host sync primitive '{name}' — a "
@@ -194,7 +200,8 @@ def _model(name: str, impl: str):
     from repro.models import Model
 
     model = Model(get_config(name), attn_impl="xla",
-                  paged_attn_impl=impl, paged_attn_page=PAGE)
+                  paged_attn_impl=impl, paged_attn_page=PAGE,
+                  interpret=impl == "pallas")
     params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
     return model, params
 

@@ -52,7 +52,6 @@ def eval_eat(
     next_pos: jax.Array,        # (B,) position the next real token would take
     *,
     entropy_impl: str = "auto",
-    interpret: bool = False,
 ) -> jax.Array:
     """Batched EAT for every sequence sharing the cache.  (B,) float32.
 
@@ -69,7 +68,7 @@ def eval_eat(
         positions = pos1d
     return model.probe_entropy(
         params, toks, positions, pos1d, cache,
-        entropy_impl=entropy_impl, interpret=interpret,
+        entropy_impl=entropy_impl,
     )
 
 

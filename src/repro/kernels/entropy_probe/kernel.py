@@ -11,14 +11,16 @@ FlashAttention-style running accumulators
     Z  = sum exp(logit - m)
     T  = sum exp(logit - m) * logit
 
-merging tiles by rescaling, and emits  H = m + log Z - T / Z  — the
-TPU-native formulation of "EAT costs one extra token" (DESIGN.md §4.2).
+merging tiles by rescaling, and emits  H = m + log Z - T / Z.
 
 Grid: (B tiles, V tiles), V innermost.  Block shapes: h (bB, d) stays
-resident across the V loop (index map ignores j); W tile (d, bV) streams.
-bV defaults to 1024 lanes; d rides whole (assigned archs: 1024..5120 →
-h tile ≤ 8x5120x4B = 160KB, W tile ≤ 5120x1024x2B = 10MB... bV is chosen
-by ``ops.py`` to keep h + W tiles within a 16MB VMEM budget).
+resident across the V loop (index map ignores j); the W tile (d, bV)
+streams in its storage dtype and feeds the MXU as is, accumulating in
+float32 — W is never upcast in VMEM.  bB is a multiple of 8 (the batch is
+padded), bV a multiple of 128 or the whole padded vocab.  The accumulators
+and the output are (bB, 128) lane-broadcast tiles: Mosaic tiles the last
+two dimensions of every block, so nothing here is rank-1.  ``ops.py`` sizes
+bV from the VMEM budget.
 """
 from __future__ import annotations
 
@@ -30,6 +32,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = -1e30
+#: lane width of the accumulator / output tiles (one vreg row)
+LANES = 128
 
 
 def _kernel(h_ref, w_ref, o_ref, m_scr, z_scr, t_scr, *, vocab, block_v, n_v):
@@ -41,27 +45,26 @@ def _kernel(h_ref, w_ref, o_ref, m_scr, z_scr, t_scr, *, vocab, block_v, n_v):
         z_scr[...] = jnp.zeros_like(z_scr)
         t_scr[...] = jnp.zeros_like(t_scr)
 
-    h = h_ref[...].astype(jnp.float32)          # (bB, d)
-    w = w_ref[...].astype(jnp.float32)          # (d, bV)
     logits = jax.lax.dot_general(
-        h, w, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )                                            # (bB, bV)
+        h_ref[...], w_ref[...], (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )                                            # (bB, bV) f32
 
     # mask padded vocab columns
     col = j * block_v + jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1)
     valid = col < vocab
     logits = jnp.where(valid, logits, _NEG_INF)
 
-    m_prev, z_prev, t_prev = m_scr[...], z_scr[...], t_scr[...]
-    m_tile = jnp.max(logits, axis=-1)
-    m_new = jnp.maximum(m_prev, m_tile)
+    m_prev = m_scr[...][:, :1]                   # (bB, 1)
+    m_new = jnp.maximum(m_prev, jnp.max(logits, axis=-1, keepdims=True))
     alpha = jnp.exp(m_prev - m_new)
-    e = jnp.where(valid, jnp.exp(logits - m_new[:, None]), 0.0)
-    z_new = z_prev * alpha + jnp.sum(e, axis=-1)
-    t_new = t_prev * alpha + jnp.sum(e * jnp.where(valid, logits, 0.0), axis=-1)
-    m_scr[...] = m_new
-    z_scr[...] = z_new
-    t_scr[...] = t_new
+    e = jnp.where(valid, jnp.exp(logits - m_new), 0.0)
+    z_new = z_scr[...][:, :1] * alpha + jnp.sum(e, axis=-1, keepdims=True)
+    t_new = t_scr[...][:, :1] * alpha + jnp.sum(
+        e * jnp.where(valid, logits, 0.0), axis=-1, keepdims=True)
+    m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
+    z_scr[...] = jnp.broadcast_to(z_new, z_scr.shape)
+    t_scr[...] = jnp.broadcast_to(t_new, t_scr.shape)
 
     @pl.when(j == n_v - 1)
     def _emit():
@@ -74,13 +77,20 @@ def entropy_probe_pallas(
     w: jax.Array,      # (d, Vp)
     vocab: int,
     *,
-    block_b: int = 8,
+    block_b: int = 0,
     block_v: int = 1024,
     interpret: bool = False,
 ) -> jax.Array:        # (B,) float32
+    """``block_b=0`` keeps the whole (8-padded) batch resident, so W streams
+    from HBM exactly once per call."""
     B, d = h.shape
     Vp = w.shape[1]
-    block_b = min(block_b, B)
+    # the matmul runs in the wider of the two storage dtypes (bf16 x bf16
+    # on the serve path), accumulating in f32
+    dt = jnp.promote_types(h.dtype, w.dtype)
+    h, w = h.astype(dt), w.astype(dt)
+    Bp = -(-B // 8) * 8
+    block_b = Bp if block_b <= 0 else min(block_b, Bp)
     block_v = min(block_v, Vp)
 
     pad_b = (-B) % block_b
@@ -89,8 +99,8 @@ def entropy_probe_pallas(
     pad_v = (-Vp) % block_v
     if pad_v:
         w = jnp.pad(w, ((0, 0), (0, pad_v)))
-    Bp, Vpp = h.shape[0], w.shape[1]
-    n_b, n_v = Bp // block_b, Vpp // block_v
+    Bpp, Vpp = h.shape[0], w.shape[1]
+    n_b, n_v = Bpp // block_b, Vpp // block_v
 
     out = pl.pallas_call(
         functools.partial(_kernel, vocab=vocab, block_v=block_v, n_v=n_v),
@@ -99,13 +109,14 @@ def entropy_probe_pallas(
             pl.BlockSpec((block_b, d), lambda i, j: (i, 0)),
             pl.BlockSpec((d, block_v), lambda i, j: (0, j)),
         ],
-        out_specs=pl.BlockSpec((block_b,), lambda i, j: (i,)),
-        out_shape=jax.ShapeDtypeStruct((Bp,), jnp.float32),
+        out_specs=pl.BlockSpec((block_b, LANES), lambda i, j: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((Bpp, LANES), jnp.float32),
         scratch_shapes=[
-            pltpu.VMEM((block_b,), jnp.float32),
-            pltpu.VMEM((block_b,), jnp.float32),
-            pltpu.VMEM((block_b,), jnp.float32),
+            pltpu.VMEM((block_b, LANES), jnp.float32),
+            pltpu.VMEM((block_b, LANES), jnp.float32),
+            pltpu.VMEM((block_b, LANES), jnp.float32),
         ],
+        name="entropy_probe",
         interpret=interpret,
     )(h, w)
-    return out[:B]
+    return out[:B, 0]

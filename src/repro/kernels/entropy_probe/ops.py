@@ -4,10 +4,11 @@
 softmax(h @ w)[:, :vocab] per row — Eq. (2) of the paper evaluated at the
 probe position (Eq. 5 / Eq. 13).
 
-Implementations:
-  * pallas — fused streaming kernel (TPU; interpret=True in tests)
+Implementations (``kernels.dispatch.resolve_impl`` picks for ``auto``):
+  * pallas — fused streaming kernel (TPU; ``interpret=True`` elsewhere)
   * xla    — chunked scan over vocab tiles with the same online (m, Z, T)
-             accumulators; memory-bounded, used on CPU and for the dry-run
+             accumulators; memory-bounded, used on CPU, on meshes and by
+             the dry-run
   * ref    — naive oracle
 """
 from __future__ import annotations
@@ -16,9 +17,37 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from repro.kernels.dispatch import resolve_impl
 from repro.kernels.entropy_probe.ref import next_token_entropy_ref
 
 _NEG_INF = -1e30
+
+#: VMEM the kernel's blocks may hold.  v5e's default scoped-VMEM limit is
+#: 16 MiB; the rest is headroom for Mosaic's own scratch.
+VMEM_BUDGET = 12 * 2**20
+
+
+def pick_block_v(B: int, d: int, Vp: int, itemsize: int) -> int:
+    """The widest vocab tile (a multiple of 128 that divides ``Vp``, so W is
+    never padded in HBM) whose VMEM footprint fits ``VMEM_BUDGET``:
+
+      W tile       d * bV * itemsize, double-buffered
+      h block      Bp * d * itemsize, double-buffered
+      f32 temps    ~4 live (Bp, bV) tiles: logits, exp, product, mask
+      accumulators 3 scratch + 2 output buffers of (Bp, 128) f32
+
+    A ``Vp`` with no 128-multiple divisor gets the widest fitting tile and
+    the kernel pads W (test shapes only: ``padded_vocab`` is 256-aligned)."""
+    Bp = -(-B // 8) * 8
+    fixed = 2 * Bp * d * itemsize + 5 * Bp * 128 * 4
+    per_col = 2 * d * itemsize + 4 * Bp * 4
+    cap = max(128, (VMEM_BUDGET - fixed) // per_col // 128 * 128)
+    if Vp <= cap:
+        return Vp
+    for bv in range(cap, 127, -128):
+        if Vp % bv == 0:
+            return bv
+    return cap
 
 
 def _xla_entropy(h, w, vocab, *, block_v=8192):
@@ -62,16 +91,16 @@ def next_token_entropy(
     *,
     impl: str = "auto",
     interpret: bool = False,
+    n_devices: int = 1,
 ) -> jax.Array:         # (B,) float32, nats
-    if impl == "auto":
-        impl = "pallas" if jax.default_backend() == "tpu" else "xla"
     if impl == "ref":
         return next_token_entropy_ref(h, w, vocab)
+    impl = resolve_impl(impl, interpret=interpret, n_devices=n_devices)
     if impl == "pallas":
         from repro.kernels.entropy_probe.kernel import entropy_probe_pallas
 
-        # keep h-tile + w-tile within ~12MB VMEM
-        d = h.shape[1]
-        block_v = max(128, min(2048, (12 * 2**20 // max(1, d * 2)) // 128 * 128))
-        return entropy_probe_pallas(h, w, vocab, block_v=block_v, interpret=interpret)
+        itemsize = jnp.promote_types(h.dtype, w.dtype).itemsize
+        block_v = pick_block_v(h.shape[0], h.shape[1], w.shape[1], itemsize)
+        return entropy_probe_pallas(h, w, vocab, block_v=block_v,
+                                    interpret=interpret)
     return _xla_entropy(h, w, vocab)

@@ -16,15 +16,16 @@ bit-identical outputs.  That per-impl invariant is what the serving stack's
 ``attn_impl != "gather"`` modes rely on (docs/architecture.md §Paged
 attention kernel).
 
-``impl``: ``auto`` (pallas on TPU, else xla), ``xla`` (the block-scan
-reference), ``pallas`` (the kernel; on non-TPU backends it runs in
-interpret mode so the path is CPU-testable end to end).
+``impl``: ``auto`` (``kernels.dispatch.resolve_impl``: the kernel on one
+TPU device, else xla), ``xla`` (the block-scan reference), ``pallas`` (the
+kernel; off the TPU only with ``interpret=True``).
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.dispatch import resolve_impl
 from repro.kernels.paged_attention.ref import (
     block_decode_attention,
     paged_attention_xla,
@@ -33,14 +34,6 @@ from repro.kernels.paged_attention.ref import (
 #: physical page id reserved as the trash page (serving.cache.PAGE_TRASH);
 #: duplicated here so the kernel package stays import-light
 PAGE_TRASH = 0
-
-
-def _resolve(impl: str, interpret: bool) -> tuple[str, bool]:
-    if impl == "auto":
-        impl = "pallas" if jax.default_backend() == "tpu" else "xla"
-    if impl == "pallas" and jax.default_backend() != "tpu":
-        interpret = True              # CPU: interpret-mode kernel
-    return impl, interpret
 
 
 def block_positions(kv_pos: jax.Array, pages: jax.Array,
@@ -71,9 +64,10 @@ def paged_decode_attention(
     scale: float | None = None,
     impl: str = "auto",
     interpret: bool = False,
+    n_devices: int = 1,
 ) -> jax.Array:
     scale = scale if scale is not None else 1.0 / (q.shape[-1] ** 0.5)
-    impl, interpret = _resolve(impl, interpret)
+    impl = resolve_impl(impl, interpret=interpret, n_devices=n_devices)
     if impl == "pallas":
         from repro.kernels.paged_attention.kernel import paged_attention_pallas
 
@@ -97,6 +91,7 @@ def ring_decode_attention(
     scale: float | None = None,
     impl: str = "auto",
     interpret: bool = False,
+    n_devices: int = 1,
 ) -> jax.Array:
     """The ring cache through the block algorithm: every logical block is
     "mapped" at its own rank, so the scan covers the whole capacity in
@@ -104,7 +99,7 @@ def ring_decode_attention(
     reproduces bit-for-bit.  A capacity that is not a page multiple is
     padded with masked slots (an exact no-op: appended identity steps)."""
     scale = scale if scale is not None else 1.0 / (q.shape[-1] ** 0.5)
-    impl, interpret = _resolve(impl, interpret)
+    impl = resolve_impl(impl, interpret=interpret, n_devices=n_devices)
     B, C = kv_pos.shape
     pad = (-C) % page_size
     if pad:

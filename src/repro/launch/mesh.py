@@ -2,12 +2,23 @@
 
 A FUNCTION (not a module-level constant) so importing never touches jax
 device state — the dry-run must set XLA_FLAGS before any device query.
+
+Every mesh is built by ``_mesh``: its axes are ``Auto``, so GSPMD
+propagates shardings from the explicit in/out shardings of each program.
+(``jax.make_mesh`` defaults to ``Explicit`` axes, under which sharding is
+part of every array's type and gathers such as the embedding lookup must
+state their output sharding.)
 """
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 from repro.sharding.partition import ShardCtx
+
+
+def _mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -16,7 +27,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     pure data parallelism (DCN-crossing gradient all-reduce)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _mesh(shape, axes)
 
 
 def make_ctx(*, multi_pod: bool = False) -> ShardCtx:
@@ -34,7 +45,7 @@ def make_device_ctx(data: int, model: int, *, fsdp: bool = False) -> ShardCtx:
     step under FSDP, so weights are replicated over ``data`` and only
     tensor-parallel over ``model`` (see ``ShardCtx.fsdp``).
     """
-    mesh = jax.make_mesh((data, model), ("data", "model"))
+    mesh = _mesh((data, model), ("data", "model"))
     return ShardCtx(mesh=mesh, batch_axes=("data",), model_axis="model",
                     fsdp=fsdp)
 

@@ -6,6 +6,10 @@
 On TPU the same launcher builds the production mesh and shards the serve
 state (the dry-run proves every assigned architecture lowers; this is the
 runtime equivalent).  On CPU it serves the synthetic-task models.
+
+``parse_args`` / ``build_engine`` / ``serve_requests`` are the launcher's
+construction, shared with ``chip_smoke.py``: the smoke drives exactly the
+engine a user's command line builds.
 """
 from __future__ import annotations
 
@@ -22,12 +26,15 @@ from repro.core.stopping import EATStopper
 from repro.data.synthetic import ChainTask, Tokens
 from repro.launch.mesh import local_ctx, make_ctx, make_device_ctx
 from repro.models import Model
+from repro.serving.cache import CacheConfig
 from repro.serving.engine import EngineConfig, ReasoningEngine
+from repro.serving.executor import init_params
 from repro.serving.sampler import SamplerConfig
 from repro.training.checkpoint import load_checkpoint
+from repro.utils.compile_cache import enable_compile_cache
 
 
-def main():
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="tiny-reasoner")
     ap.add_argument("--ckpt", default=None)
@@ -35,12 +42,19 @@ def main():
     ap.add_argument("--delta", type=float, default=1e-3)
     ap.add_argument("--alpha", type=float, default=0.2)
     ap.add_argument("--budget", type=int, default=96)
+    ap.add_argument("--eval-every", type=int, default=0, metavar="N",
+                    help="EAT evaluation schedule: every N reasoning tokens "
+                         "(0 = at each paragraph-break token, the paper's "
+                         "schedule)")
     ap.add_argument("--local", action="store_true")
     ap.add_argument("--multipod", action="store_true")
     ap.add_argument("--mesh", default=None, metavar="DATAxMODEL",
                     help="serve on a (data x model) mesh over the visible "
                          "devices, e.g. --mesh 4x2 (overrides --local / the "
                          "production mesh)")
+    ap.add_argument("--greedy", action="store_true",
+                    help="greedy decoding (default: temperature 0.6, "
+                         "top-p 0.95)")
     ap.add_argument("--top-k", type=int, default=0,
                     help="top-k sampling cutoff (0 = off)")
     ap.add_argument("--typical-p", type=float, default=1.0,
@@ -55,9 +69,13 @@ def main():
                          "(materialize the paged cache's logical view) or "
                          "the page-native path (auto/xla/pallas — K/V read "
                          "straight off the page pools through the mapped-"
-                         "page list, O(mapped pages) per token; 'pallas' "
-                         "runs the TPU kernel, in interpret mode on CPU — "
-                         "docs/serving.md)")
+                         "page list, O(mapped pages) per token; auto = the "
+                         "Pallas kernel on one TPU device, xla elsewhere; "
+                         "'pallas' compiles the TPU kernel, and off the TPU "
+                         "needs --interpret — docs/serving.md)")
+    ap.add_argument("--interpret", action="store_true",
+                    help="run the Pallas kernels in interpret mode (CPU "
+                         "runs of --attn-impl pallas)")
     ap.add_argument("--chunk", type=int, default=32,
                     help="decode steps per jitted dispatch")
     ap.add_argument("--requests", type=int, default=0,
@@ -95,8 +113,12 @@ def main():
                     help="monitor=proxy: give the proxy its own (smaller) "
                          "mesh over the visible devices, e.g. 1x2 "
                          "(default: share the generator's context)")
-    args = ap.parse_args()
+    return ap
 
+
+def parse_args(argv=None) -> argparse.Namespace:
+    ap = build_parser()
+    args = ap.parse_args(argv)
     if args.monitor == "proxy" and not args.requests:
         ap.error("--monitor proxy serves through the scheduler: pass "
                  "--requests N")
@@ -107,66 +129,54 @@ def main():
                                     or args.proxy_mesh):
         ap.error("--proxy-config/--proxy-ckpt/--proxy-mesh only apply with "
                  "--monitor proxy (default monitor is 'self')")
+    return args
 
+
+def _mesh_ctx(spec: str):
+    d, m = (int(x) for x in spec.lower().split("x"))
+    return make_device_ctx(d, m)
+
+
+def _params(model: Model, ckpt: str | None, seed: int, what: str):
+    if ckpt:
+        like = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0)))
+        return load_checkpoint(ckpt, like)
+    print(f"WARNING: no {what}checkpoint — random {what}weights")
+    return init_params(model, jax.random.PRNGKey(seed))
+
+
+def build_engine(args: argparse.Namespace,
+                 prompt_width: int) -> ReasoningEngine:
+    """The engine ``args`` describe.  ``prompt_width`` (the left-padded
+    prompt length) sizes the batch-lifetime cache of ``--requests``
+    serving."""
     cfg = get_config(args.arch)
     if args.mesh:
-        d, m = (int(x) for x in args.mesh.lower().split("x"))
-        ctx = make_device_ctx(d, m)
+        ctx = _mesh_ctx(args.mesh)
     elif args.local:
         ctx = local_ctx()
     else:
         ctx = make_ctx(multi_pod=args.multipod)
-    model = Model(cfg, ctx, attn_impl="xla")
-    if args.ckpt:
-        like = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0)))
-        params = load_checkpoint(args.ckpt, like)
-    else:
-        print("WARNING: no checkpoint — random weights")
-        params = model.init(jax.random.PRNGKey(0))
+    model = Model(cfg, ctx, attn_impl="xla", interpret=args.interpret)
+    params = _params(model, args.ckpt, 0, "")
 
-    from repro.serving.cache import CacheConfig
-
+    sampler = (SamplerConfig(greedy=True) if args.greedy else
+               SamplerConfig(temperature=0.6, top_p=0.95, top_k=args.top_k,
+                             typical_p=args.typical_p, min_p=args.min_p))
     ecfg = EngineConfig(
         max_reasoning_tokens=args.budget, capacity=args.budget + 128,
         pad_id=Tokens.PAD, end_think_id=Tokens.END_THINK,
         newline_id=Tokens.NEWLINE, eos_id=Tokens.EOS,
-        sampler=SamplerConfig(temperature=0.6, top_p=0.95,
-                              top_k=args.top_k, typical_p=args.typical_p,
-                              min_p=args.min_p),
-        cache=CacheConfig(attn_impl=args.attn_impl),
+        sampler=sampler, cache=CacheConfig(attn_impl=args.attn_impl),
+        chunk_len=args.chunk,
     )
+    schedule = (dict(schedule="every_n", every_n=args.eval_every)
+                if args.eval_every else {})
     monitor = ReasoningMonitor(
         stopper=EATStopper(alpha=args.alpha, delta=args.delta),
         probe=make_probe(Tokens.END_THINK, (Tokens.ANS,)),
-        newline_id=Tokens.NEWLINE,
+        newline_id=Tokens.NEWLINE, **schedule,
     )
-    ecfg.chunk_len = args.chunk
-
-    proxy = None
-    if args.monitor == "proxy":
-        from repro.serving.proxy import ProxyConfig
-
-        proxy_cfg = get_config(args.proxy_config or args.arch)
-        if proxy_cfg.vocab != cfg.vocab:
-            raise SystemExit(f"proxy arch {proxy_cfg.name} must share the "
-                             f"generator's tokenizer (vocab {cfg.vocab}, "
-                             f"got {proxy_cfg.vocab})")
-        if args.proxy_mesh:
-            d, m = (int(x) for x in args.proxy_mesh.lower().split("x"))
-            proxy_ctx = make_device_ctx(d, m)
-        else:
-            proxy_ctx = ctx
-        proxy_model = Model(proxy_cfg, proxy_ctx, attn_impl="xla")
-        if args.proxy_ckpt:
-            like = jax.eval_shape(
-                lambda: proxy_model.init(jax.random.PRNGKey(0)))
-            proxy_params = load_checkpoint(args.proxy_ckpt, like)
-        else:
-            print("WARNING: no proxy checkpoint — random proxy weights")
-            proxy_params = proxy_model.init(jax.random.PRNGKey(1))
-        proxy = ProxyConfig(model=proxy_model, params=proxy_params)
-
-    task = ChainTask()
     if args.requests:
         # continuous batching: args.batch slots over a longer request
         # queue; early-exiting sequences free their slot for the next
@@ -178,10 +188,8 @@ def main():
         # engine bakes --attn-impl/--page-size into its model.
         from repro.serving.scheduler import SlotScheduler
 
-        batch = task.serve_batch(np.random.default_rng(0), args.requests)
         ecfg.capacity = SlotScheduler.required_capacity(
-            batch["prompts"].shape[1], args.requests, args.batch, args.budget
-        )
+            prompt_width, args.requests, args.batch, args.budget)
         if args.overlap == "on":
             # the overlapped loop's ring guard adds one in-flight chunk to
             # its (host-mirror) pointer estimate — give it that headroom
@@ -190,13 +198,44 @@ def main():
                                  num_pages=args.num_pages,
                                  attn_impl=args.attn_impl)
 
-    engine = ReasoningEngine(model, params, ecfg, monitor, proxy=proxy)
+    proxy = None
+    if args.monitor == "proxy":
+        from repro.serving.proxy import ProxyConfig
+
+        proxy_cfg = get_config(args.proxy_config or args.arch)
+        if proxy_cfg.vocab != cfg.vocab:
+            raise SystemExit(f"proxy arch {proxy_cfg.name} must share the "
+                             f"generator's tokenizer (vocab {cfg.vocab}, "
+                             f"got {proxy_cfg.vocab})")
+        proxy_ctx = _mesh_ctx(args.proxy_mesh) if args.proxy_mesh else ctx
+        proxy_model = Model(proxy_cfg, proxy_ctx, attn_impl="xla",
+                            interpret=args.interpret)
+        proxy_params = _params(proxy_model, args.proxy_ckpt, 1, "proxy ")
+        proxy = ProxyConfig(model=proxy_model, params=proxy_params)
+
+    return ReasoningEngine(model, params, ecfg, monitor, proxy=proxy)
+
+
+def serve_requests(engine: ReasoningEngine, args: argparse.Namespace,
+                   batch: dict, *, record_trace: bool = False) -> list[dict]:
+    """``--requests`` serving: the whole queue through ``--batch`` slots,
+    with a 4-token forced answer harvested from each request."""
+    return engine.serve(batch["prompts"], batch["prompt_len"],
+                        jax.random.PRNGKey(0), batch_size=args.batch,
+                        answer_len=4, overlap=args.overlap == "on",
+                        record_trace=record_trace)
+
+
+def main():
+    enable_compile_cache()
+    args = parse_args()
+    task = ChainTask()
+    batch = task.serve_batch(np.random.default_rng(0),
+                             args.requests or args.batch)
+    engine = build_engine(args, batch["prompts"].shape[1])
 
     if args.requests:
-        results = engine.serve(batch["prompts"], batch["prompt_len"],
-                               jax.random.PRNGKey(0), batch_size=args.batch,
-                               answer_len=4,
-                               overlap=args.overlap == "on")
+        results = serve_requests(engine, args, batch)
         ans = np.array([ChainTask.extract_answer(r["answer_tokens"][None])[0]
                         for r in results])
         n = np.array([r["n_reasoning"] for r in results])
@@ -207,7 +246,6 @@ def main():
               f"reasoning tokens: total={n.sum()} per-q={n}")
         return
 
-    batch = task.serve_batch(np.random.default_rng(0), args.batch)
     st = engine.start(jnp.asarray(batch["prompts"]), jnp.asarray(batch["prompt_len"]),
                       jax.random.PRNGKey(0))
     st = engine.reason(st)

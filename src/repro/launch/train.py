@@ -30,9 +30,11 @@ from repro.training.train_loop import (
     init_train_state,
     jit_train_step,
 )
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--steps", type=int, default=100)

@@ -43,6 +43,11 @@ class Model:
     # A/B (the engine threads both from EngineConfig.cache).
     paged_attn_impl: str = "gather"
     paged_attn_page: int = 16
+    # run the Pallas kernels (paged attention, entropy probe) in interpret
+    # mode: the one switch that lets a CPU test drive the serving stack
+    # through the kernels.  Off, a kernel impl compiles for the TPU, and
+    # ``impl="pallas"`` on another backend is an error.
+    interpret: bool = False
 
     # ---------------------------------------------------------------- init
     def init(self, key) -> dict:
@@ -143,6 +148,7 @@ class Model:
             params["stack"], x, positions, pos1d, slots, cache, cfg, ctx,
             attn_impl=self.attn_impl, window=window, unroll=self.unroll,
             paged_impl=self.paged_attn_impl, page_block=self.paged_attn_page,
+            interpret=self.interpret,
         )
         return hidden, cache
 
@@ -159,12 +165,13 @@ class Model:
             params["stack"], x, positions, pos1d, slots, cache, cfg, ctx,
             attn_impl=self.attn_impl, window=window, unroll=self.unroll,
             paged_impl=self.paged_attn_impl, page_block=self.paged_attn_page,
+            interpret=self.interpret,
         )
         return self.logits(params, hidden), cache
 
     def decode_and_probe(self, params, token, positions, pos1d, cache,
                          probe_tokens, *, window: int | None = None,
-                         entropy_impl: str = "auto", interpret: bool = False):
+                         entropy_impl: str = "auto"):
         """Fused serve step (§Perf): ONE forward over [token, probe...]
         instead of decode + separate probe — halves the per-step weight
         traffic (under FSDP: one all-gather instead of two).
@@ -192,7 +199,6 @@ class Model:
             eat = self.probe_entropy(
                 params, probe_tokens, positions[:, 1:1 + m], pos1d[:, 1:1 + m],
                 cache, window=window, entropy_impl=entropy_impl,
-                interpret=interpret,
             )
             return logits, eat, cache
         window = cfg.sliding_window if window is None else window
@@ -204,18 +210,16 @@ class Model:
             params["stack"], x, positions, pos1d, slots, cache, cfg, ctx,
             attn_impl=self.attn_impl, window=window, unroll=self.unroll,
             paged_impl=self.paged_attn_impl, page_block=self.paged_attn_page,
+            interpret=self.interpret,
         )
         new_cache["cur"] = cache["cur"] + 1            # commit decode only
         logits = self.logits(params, hidden[:, :1])
         w = self.unembed_matrix(params)
-        eat = next_token_entropy(
-            hidden[:, -1], w, cfg.vocab, impl=entropy_impl, interpret=interpret
-        )
+        eat = self.entropy(hidden[:, -1], w, entropy_impl)
         return logits, eat, new_cache
 
     def probe_entropy(self, params, probe_tokens, positions, pos1d, cache, *,
-                      window: int | None = None, entropy_impl: str = "auto",
-                      interpret: bool = False):
+                      window: int | None = None, entropy_impl: str = "auto"):
         """EAT (paper Eq. 5/13): run the probe tokens (``</think>`` + optional
         prefix) against the cache WITHOUT committing it, and return the
         next-token entropy at the last probe position.  (B,) float32 nats."""
@@ -228,12 +232,19 @@ class Model:
             params["stack"], x, positions, pos1d, slots, cache, cfg, ctx,
             attn_impl=self.attn_impl, window=window, unroll=self.unroll,
             paged_impl=self.paged_attn_impl, page_block=self.paged_attn_page,
+            interpret=self.interpret,
         )
         h_last = hidden[:, -1]
         w = self.unembed_matrix(params)
-        return next_token_entropy(
-            h_last, w, cfg.vocab, impl=entropy_impl, interpret=interpret
-        )
+        return self.entropy(h_last, w, entropy_impl)
+
+    def entropy(self, h, w, impl: str = "auto") -> jax.Array:
+        """Next-token entropy over the valid vocabulary (the probe's last
+        step), through the kernel dispatch with this model's interpret
+        switch and device count."""
+        return next_token_entropy(h, w, self.cfg.vocab, impl=impl,
+                                  interpret=self.interpret,
+                                  n_devices=self.ctx.n_devices)
 
 
 def cross_entropy_loss(logits, targets, mask, vocab: int, *, z_loss: float = 1e-4):

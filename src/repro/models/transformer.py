@@ -247,7 +247,7 @@ def attn_block_cached(
     use_moe: bool, window: int = 0, attn_impl: str = "auto",
     cross_cache: tuple | None = None, enc_pos=None, x_extra=None,
     paged: tuple | None = None, paged_impl: str = "gather",
-    page_block: int = 16,
+    page_block: int = 16, interpret: bool = False,
 ):
     """Cached block (prefill m=S / decode m small).  Returns (x, entry, aux).
 
@@ -317,6 +317,7 @@ def attn_block_cached(
                     q, entry["k"], entry["v"], blocks["pages"],
                     blocks["count"], bpos, pos1d, window=window,
                     scale=att.attn_scale(cfg), impl=paged_impl,
+                    interpret=interpret, n_devices=ctx.n_devices,
                 )
             else:
                 k_view = gather_pages(entry["k"], table)
@@ -345,6 +346,7 @@ def attn_block_cached(
             o = paged_ops.ring_decode_attention(
                 q, k_view, v_view, pos1d, kv_pos, page_size=ps,
                 window=window, scale=att.attn_scale(cfg), impl=paged_impl,
+                interpret=interpret, n_devices=ctx.n_devices,
             )
         else:
             o = att.attention(
@@ -522,7 +524,7 @@ def forward_cached(
     params: Params, x, positions, pos1d, slots, cache: Cache,
     cfg: ModelConfig, ctx: ShardCtx, *,
     attn_impl: str = "auto", window: int = 0, unroll: bool = False,
-    paged_impl: str = "gather", page_block: int = 16,
+    paged_impl: str = "gather", page_block: int = 16, interpret: bool = False,
 ) -> tuple[jax.Array, Cache, jax.Array]:
     """Unified prefill (m=S) / decode / probe forward against a cache.
 
@@ -572,6 +574,7 @@ def forward_cached(
                     use_moe=use_moe, window=window, attn_impl=attn_impl,
                     cross_cache=cc, enc_pos=cache.get("enc_pos"), paged=paged,
                     paged_impl=paged_impl, page_block=page_block,
+                    interpret=interpret,
                 )
                 if cross:  # cross kv is static; don't re-emit to save copies
                     entry_new["ck"], entry_new["cv"] = entry["ck"], entry["cv"]
@@ -622,6 +625,7 @@ def forward_cached(
                 attn_entry, kv_pos, slots, use_moe=False, window=window,
                 attn_impl=attn_impl, x_extra=emb0, paged=paged,
                 paged_impl=paged_impl, page_block=page_block,
+                interpret=interpret,
             )
             return (xx, aux + a), (st_group_new, attn_entry_new)
 

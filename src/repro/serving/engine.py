@@ -658,7 +658,9 @@ class ReasoningEngine:
         can fall on any token — ``every_n`` for the fixed stride), so the
         per-evaluation host hooks below still run between chunks."""
         trace: list[dict] = []
-        rng = state.rng
+        # a copy, not a reference: the chunk below donates ``state``, and
+        # its rng buffer with it
+        rng = jnp.copy(state.rng)
         newline_sched = self.monitor.schedule == "newline"
         chunk = jnp.asarray(1 if newline_sched else self.monitor.every_n,
                             jnp.int32)

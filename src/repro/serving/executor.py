@@ -348,6 +348,21 @@ def build_serve_step_program(model: Model, scfg: ServeStepConfig,
     return jax.jit(serve_step, in_shardings=in_sh, donate_argnums=1), mon_struct
 
 
+def init_params(model: Model, key) -> dict:
+    """Random parameters, made where they live: on a mesh, ``model.init``
+    is jitted with the ``param_pspecs`` out-shardings, so each device
+    computes only its own shards and no parameter ever exists whole on one
+    device (a model that only several chips can hold is initialised on
+    them).  jax's partitionable threefry makes the values independent of
+    the mesh."""
+    ctx = model.ctx
+    if ctx.mesh is None:
+        return model.init(key)
+    struct = jax.eval_shape(model.init, key)
+    out_sh = mesh_shardings(ctx, param_pspecs(struct, model.cfg, ctx))
+    return jax.jit(model.init, out_shardings=out_sh)(key)
+
+
 def build_stream_monitor_programs(model: Model, probe: ProbeSpec):
     """Jitted programs for the host-streaming ``ProxyMonitor``
     (serving/proxy.py): ``(consume, probe_fn, prefill)``.

@@ -61,6 +61,10 @@ class ShardCtx:
         return self.mesh.shape[self.model_axis]
 
     @property
+    def n_devices(self) -> int:
+        return 1 if self.mesh is None else self.mesh.size
+
+    @property
     def data_size(self) -> int:
         if self.mesh is None:
             return 1

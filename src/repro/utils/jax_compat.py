@@ -1,67 +1,28 @@
-"""Single choke-point for jax API churn.
+"""Single choke-point for jax API churn (jax 0.9).
 
-Everything here exists because the public jax surface moved between 0.4.x
-and 0.5+/0.6+; routing all call sites through one module makes the next
-jax bump a one-file change:
-
-* ``shard_map``       — lived in ``jax.experimental.shard_map`` through
-  0.4.x, was promoted to ``jax.shard_map`` later; the replication-check
-  kwarg was also renamed ``check_rep`` -> ``check_vma``.
-* ``make_abstract_mesh`` — ``AbstractMesh``'s calling convention changed
-  from ``AbstractMesh(((name, size), ...))`` pairs (0.4.x) to
-  ``AbstractMesh(axis_sizes, axis_names)``.
-* ``cost_analysis_dict`` — ``Compiled.cost_analysis()`` returns a list of
-  per-computation dicts on 0.4.x and a plain dict on newer releases.
+* ``shard_map``          — ``jax.shard_map`` with the replication check
+  spelled ``check_vma``.
+* ``make_abstract_mesh`` — device-free ``AbstractMesh(axis_sizes,
+  axis_names)`` for pure sharding-spec logic.
+* ``cost_analysis_dict`` — ``Compiled.cost_analysis()`` as a dict, empty
+  when the backend reports nothing.
 """
 from __future__ import annotations
 
 import jax
 
-try:  # jax >= 0.5: public top-level export
-    from jax import shard_map as _shard_map  # type: ignore[attr-defined]
-    _VMA_KWARG = "check_vma"
-except ImportError:  # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _VMA_KWARG = "check_rep"
-
 
 def shard_map(f, mesh, in_specs, out_specs, check_vma: bool = True):
-    """``jax.shard_map`` with the replication-check kwarg normalized.
-
-    ``check_vma`` follows the new-jax name; on 0.4.x it is forwarded as
-    ``check_rep`` (same semantics: verify out_specs replication claims).
-    """
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **{_VMA_KWARG: check_vma})
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 
 def make_abstract_mesh(shape: tuple[int, ...], axis_names: tuple[str, ...]):
     """Device-free mesh for pure sharding-spec logic (no real devices)."""
     from jax.sharding import AbstractMesh
 
-    try:  # jax >= 0.5-ish: AbstractMesh(axis_sizes, axis_names)
-        return AbstractMesh(shape, axis_names)
-    except TypeError:  # jax 0.4.x: AbstractMesh(((name, size), ...))
-        return AbstractMesh(tuple(zip(axis_names, shape)))
+    return AbstractMesh(shape, axis_names)
 
 
 def cost_analysis_dict(compiled) -> dict:
-    """Normalized ``Compiled.cost_analysis()``: always one flat dict.
-
-    jax 0.4.x returns ``[{...}]`` (one dict per computation, usually a
-    singleton); newer jax returns the dict directly.  Multi-entry lists are
-    summed key-wise — callers read aggregate flops / bytes accessed.
-    """
-    cost = compiled.cost_analysis()
-    if cost is None:
-        return {}
-    if isinstance(cost, dict):
-        return cost
-    out: dict = {}
-    for entry in cost:
-        for k, v in entry.items():
-            if isinstance(v, (int, float)):
-                out[k] = out.get(k, 0.0) + v
-            else:
-                out.setdefault(k, v)
-    return out
+    return compiled.cost_analysis() or {}

@@ -157,9 +157,9 @@ def test_ring_op_pads_non_multiple_capacity():
 
 
 def _engine(kind, attn, *, num_pages=0, capacity=256, delta=1e9, budget=24,
-            proxy=False, chunk_len=8):
+            proxy=False, chunk_len=8, interpret=False):
     cfg = get_config("tiny")
-    model = Model(cfg, attn_impl="xla")
+    model = Model(cfg, attn_impl="xla", interpret=interpret)
     params = model.init(jax.random.PRNGKey(11))
     ecfg = EngineConfig(
         max_reasoning_tokens=budget, capacity=capacity,
@@ -252,7 +252,7 @@ def test_pallas_interpret_serve_smoke():
     ref = _engine("paged", "xla", **kw).serve(
         b["prompts"], b["prompt_len"], jax.random.PRNGKey(0), batch_size=2,
         max_tokens=8)
-    out = _engine("paged", "pallas", **kw).serve(
+    out = _engine("paged", "pallas", interpret=True, **kw).serve(
         b["prompts"], b["prompt_len"], jax.random.PRNGKey(0), batch_size=2,
         max_tokens=8)
     for r, o in zip(ref, out):
