@@ -46,6 +46,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+import re
+
 from repro.analysis.common import PassResult, Violation
 
 # audit-sized serving geometry: tiny model, 2 rows, 4 blocks of 8 slots
@@ -69,7 +71,7 @@ REGIMES = (("exit", 1e-3), ("never", 1e9))
 _DONATION_CELLS = {
     ("self", "ring"): ("chunk", "chunk_snapshot", "decode", "prefill",
                        "probe", "admit", "rollout", "serve_step"),
-    ("self", "paged"): ("pack", "admit"),
+    ("self", "paged"): ("pack", "admit_paged"),
     ("proxy", "ring"): ("shadow", "retract", "retract_lagged"),
 }
 
@@ -260,6 +262,7 @@ class _Audit:
         self.n_lowered = 0
         self.n_donation_checked = 0
         self.families: set = set()
+        self.modules: dict = {}      # family -> lowered module names
 
     def program(self, tag: tuple, family: str, prog, args, *,
                 donate: bool | None = None, compile_donation: bool = False):
@@ -269,9 +272,13 @@ class _Audit:
             jaxpr = prog.trace(*args).jaxpr
             self.violations += scan_jaxpr(jaxpr, where)
             lowered = prog.lower(*args)
-            self.violations += scan_hlo_text(lowered.as_text(), where)
+            text = lowered.as_text()
+            self.violations += scan_hlo_text(text, where)
             self.keys.add(tag)
             self.families.add(family)
+            m = re.search(r"module @([\w.]+)", text)
+            self.modules.setdefault(family, set()).add(
+                m.group(1) if m else "?")
             self.n_lowered += 1
             if compile_donation and donate is not None:
                 self.violations += check_donation(
@@ -362,10 +369,10 @@ def _audit_self_cell(a: _Audit, kind: str, impl: str):
                       (cache, dense, _i32((B, NB))),
                       donate=True, compile_donation=dc("pack"))
             one = _state_struct(cfg, monitor, _dense_struct(cfg, 1, C_PRE), 1)
-            a.program(tag(("admit", B, "paged", C_PRE)), "admit",
+            a.program(tag(("admit", B, "paged", C_PRE)), "admit_paged",
                       ex.admit_paged_program(state, one),
                       (state, one, s0, _i32((NB,))),
-                      donate=True, compile_donation=dc("admit"))
+                      donate=True, compile_donation=dc("admit_paged"))
 
     # the dry-run's every-token step, both regimes — the exact program
     # launch.dryrun lowers and costs out (gather cells only: the regime
@@ -464,21 +471,28 @@ def _audit_proxy_cell(a: _Audit, kind: str, impl: str):
     # the host-streaming ProxyMonitor's programs (built by the executor
     # module for proxy.py — the layering fix this PR) — once is enough
     if kind == "ring" and impl == "gather":
-        consume, probe_fn, _prefill = build_stream_monitor_programs(
+        consume, probe_fn, prefill = build_stream_monitor_programs(
             pmodel, _monitor(1e-3).probe)
         dense = _dense_struct(pmodel.cfg, B, CAP)
         a.program(("proxy", kind, impl, "exit", "('stream_consume',)"),
-                  "stream", consume,
+                  "stream_consume", consume,
                   (pparams, dense, _i32((B, PROMPT)), _i32((B,))))
         a.program(("proxy", kind, impl, "exit", "('stream_probe',)"),
-                  "stream", probe_fn, (pparams, dense, _i32((B,))))
+                  "stream_probe", probe_fn, (pparams, dense, _i32((B,))))
+        a.program(("proxy", kind, impl, "exit", "('stream_prefill',)"),
+                  "stream_prefill", prefill,
+                  (pparams, _i32((B, PROMPT)), _i32((B, PROMPT)),
+                   _i32((B, PROMPT)), dense))
 
 
-def run(quick: bool = False) -> PassResult:
+def run(quick: bool = False, cells: list | None = None) -> PassResult:
+    """The audit over every (tier, kind, impl) cell, two with ``quick``, or
+    the ``cells`` given."""
     a = _Audit()
-    cells = [(t, k, i) for t in TIERS for k in KINDS for i in IMPLS]
-    if quick:
-        cells = [("self", "ring", "gather"), ("proxy", "paged", "xla")]
+    if cells is None:
+        cells = [(t, k, i) for t in TIERS for k in KINDS for i in IMPLS]
+        if quick:
+            cells = [("self", "ring", "gather"), ("proxy", "paged", "xla")]
     for tier, kind, impl in cells:
         if tier == "self":
             _audit_self_cell(a, kind, impl)
@@ -493,5 +507,6 @@ def run(quick: bool = False) -> PassResult:
         "distinct_keys": len(a.keys),
         "donation_checked": a.n_donation_checked,
         "families": sorted(a.families),
+        "modules": {f: sorted(m) for f, m in sorted(a.modules.items())},
         "quick": quick,
     })

@@ -10,10 +10,15 @@ runtime equivalent).  On CPU it serves the synthetic-task models.
 ``parse_args`` / ``build_engine`` / ``serve_requests`` are the launcher's
 construction, shared with ``chip_smoke.py``: the smoke drives exactly the
 engine a user's command line builds.
+
+With ``--requests`` the last line printed is the serve call's telemetry
+summary as one JSON object: per span its count, total and self seconds,
+and every counter (``serving/telemetry.py``, docs/serving.md).
 """
 from __future__ import annotations
 
 import argparse
+import json
 
 import jax
 import jax.numpy as jnp
@@ -30,6 +35,7 @@ from repro.serving.cache import CacheConfig
 from repro.serving.engine import EngineConfig, ReasoningEngine
 from repro.serving.executor import init_params
 from repro.serving.sampler import SamplerConfig
+from repro.serving.telemetry import ServeResults
 from repro.training.checkpoint import load_checkpoint
 from repro.utils.compile_cache import enable_compile_cache
 
@@ -217,7 +223,8 @@ def build_engine(args: argparse.Namespace,
 
 
 def serve_requests(engine: ReasoningEngine, args: argparse.Namespace,
-                   batch: dict, *, record_trace: bool = False) -> list[dict]:
+                   batch: dict, *, record_trace: bool = False
+                   ) -> ServeResults:
     """``--requests`` serving: the whole queue through ``--batch`` slots,
     with a 4-token forced answer harvested from each request."""
     return engine.serve(batch["prompts"], batch["prompt_len"],
@@ -244,6 +251,7 @@ def main():
         print(f"answers: {ans}  truth: {batch['answers']}")
         print(f"correct: {(ans == batch['answers']).mean():.2f}  "
               f"reasoning tokens: total={n.sum()} per-q={n}")
+        print(json.dumps(results.telemetry.summary()))
         return
 
     st = engine.start(jnp.asarray(batch["prompts"]), jnp.asarray(batch["prompt_len"]),

@@ -69,6 +69,7 @@ from repro.serving.scheduler import (
     SlotScheduler,
     pools_can_admit,
 )
+from repro.serving.telemetry import ServeResults, Telemetry
 
 __all__ = ["CacheConfig", "EngineConfig", "ProxyConfig", "ReasoningEngine",
            "ServeState"]
@@ -159,13 +160,13 @@ class ReasoningEngine:
     def monitor_mode(self) -> str:
         return "proxy" if self.proxy is not None else "self"
 
-    def _across_tiers(self, tree):
+    def _across_tiers(self, tree, tel: Telemetry):
         """Ferry per-row scalars between the generator's and the proxy's
         meshes (a host hop of a few KB, both directions; identity when the
         tiers share a ctx — the common case)."""
         if self.proxy_executor.ctx.mesh is self.executor.ctx.mesh:
             return tree
-        return jax.tree_util.tree_map(np.asarray, tree)
+        return tel.fetch("across_tiers", tree)
 
     def _positions(self, pos1d):
         return positions_for(self.model.cfg, pos1d)
@@ -288,7 +289,7 @@ class ReasoningEngine:
 
     def _serve_setup(self, prompts, prompt_len, rng, *, batch_size: int,
                      max_tokens: int | None, use_monitor: bool,
-                     chunk_len: int | None,
+                     chunk_len: int | None, tel: Telemetry,
                      overlap: bool = False) -> SimpleNamespace:
         """Shared front half of both serve loops (sync below, overlapped in
         ``serving.pipeline``): parse the request list, build the scheduler /
@@ -299,7 +300,9 @@ class ReasoningEngine:
         mirror from it instead of re-syncing).  ``overlap`` widens the
         auto-sized page pool by one row allotment: the pipeline parks a
         harvested row's pages on the in-flight fence for one boundary, so
-        a slot's old and new occupant briefly double-book its footprint."""
+        a slot's old and new occupant briefly double-book its footprint.
+        ``tel`` is the call's recorder; the cohort's prefill and pack run
+        in its ``serve.setup`` span."""
         prompts_np = np.asarray(prompts)
         plen_np = np.asarray(prompt_len)
         n_req = prompts_np.shape[0]
@@ -343,37 +346,38 @@ class ReasoningEngine:
         # shadows each chunk, feeding exits back through retract
         proxy_mode = use_monitor and self.proxy is not None
         ptier = None
-        self._ptier = None       # kept for post-serve stats (tests/benches)
         if proxy_mode:
-            ptier = self._ptier = ProxyTier(
+            ptier = ProxyTier(
                 self.proxy_executor, self.proxy_params, self.ecfg,
                 self.monitor, self.proxy.cache or ccfg,
                 self.proxy.capacity or self.ecfg.capacity, budget,
+                telemetry=tel,
             )
         gen_monitor = use_monitor and not proxy_mode
 
-        cohort = sched.start_batch()
-        rng, sub = jax.random.split(rng)
-        state = self.start(jnp.asarray(prompts_np[:B]),
-                           jnp.asarray(plen_np[:B]), sub,
-                           capacity=C_pre if paged else None)
-        if paged:
+        with tel.span("serve.setup"):
+            cohort = sched.start_batch()
+            rng, sub = jax.random.split(rng)
+            state = self.start(jnp.asarray(prompts_np[:B]),
+                               jnp.asarray(plen_np[:B]), sub,
+                               capacity=C_pre if paged else None)
+            if paged:
+                for req in cohort:
+                    alloc.ensure(req.slot, 0, S - 1)   # the prompt pages
+                template = alloc_paged_template(
+                    self.model.cfg, B, C_log, ps, num_pages, alloc=alloc,
+                    native=ccfg.attn_impl != "gather")
+                state = state._replace(cache=self.executor.pack_paged(
+                    template, state.cache, alloc.table))
+            if ptier is not None:
+                ptier.start_batch(prompts_np[:B], plen_np[:B],
+                                  [req.slot for req in cohort])
             for req in cohort:
-                alloc.ensure(req.slot, 0, S - 1)       # the prompt pages
-            template = alloc_paged_template(
-                self.model.cfg, B, C_log, ps, num_pages, alloc=alloc,
-                native=ccfg.attn_impl != "gather")
-            state = state._replace(cache=self.executor.pack_paged(
-                template, state.cache, alloc.table))
-        if ptier is not None:
-            ptier.start_batch(prompts_np[:B], plen_np[:B],
-                              [req.slot for req in cohort])
-        for req in cohort:
-            req.begin_decode()
-        cur0 = int(state.cache["cur"])
-        sched.check_capacity(cur0, "the initial batch")
-        if ptier is not None:
-            ptier.check_capacity("the initial batch")
+                req.begin_decode()
+            cur0 = int(tel.fetch("cur", state.cache["cur"]))
+            sched.check_capacity(cur0, "the initial batch")
+            if ptier is not None:
+                ptier.check_capacity("the initial batch")
 
         # the generator only pays a probe tail when IT runs the probe; in
         # proxy mode that tail belongs to the proxy tier's pool
@@ -384,14 +388,40 @@ class ReasoningEngine:
             chunk=chunk, requests=requests, sched=sched, paged=paged,
             alloc=alloc, C_pre=C_pre, proxy_mode=proxy_mode, ptier=ptier,
             gen_monitor=gen_monitor, gen_tail=gen_tail, rng=rng, state=state,
-            cur0=cur0,
+            cur0=cur0, tel=tel,
         )
+
+    def _serve_finish(self, ss: SimpleNamespace, cur_end: int
+                      ) -> ServeResults:
+        """The shared end of both serve loops: drop the proxy tier's device
+        buffers (its KV cache/pool is the tier's largest allocation), set
+        the job's counters, and hand the results back with the recorder.
+        ``cur_end`` is the ring pointer after the last chunk: the pointer
+        advances once per batch-wide decode step (prefill and rollouts do
+        not move it), so its advance is the job's ``decode_steps``."""
+        tel = ss.tel
+        if ss.ptier is not None:
+            ss.ptier.state = None
+        results = [r.to_result() for r in ss.requests]
+        for name in ("chunks", "admissions", "host_reads"):
+            tel.count(name, 0)
+        tel.count("slots", ss.B)
+        tel.count("decode_steps", cur_end - ss.cur0)
+        tel.count("row_steps", sum(r["n_reasoning"] - 1 for r in results))
+        for pool, a in (("gen", ss.alloc),
+                        ("proxy", ss.ptier.alloc if ss.ptier else None)):
+            if a is not None:
+                tel.count(f"{pool}.pool_pages", a.num_pages - 1)  # data pages
+                tel.count(f"{pool}.peak_pages", a.peak_pages_in_use)
+                tel.count(f"{pool}.pages_reused", a.pages_reused)
+                tel.count(f"{pool}.deferrals", a.deferrals)
+        return ServeResults(results, tel)
 
     def serve(self, prompts, prompt_len, rng, *, batch_size: int,
               max_tokens: int | None = None, use_monitor: bool = True,
               chunk_len: int | None = None, answer_len: int = 0,
               record_trace: bool = False, overlap: bool = False,
-              pipeline_hooks=None) -> list[dict]:
+              pipeline_hooks=None) -> ServeResults:
         """Continuous-batching serving loop over N requests with
         ``batch_size`` slots.
 
@@ -424,14 +454,16 @@ class ReasoningEngine:
         (``scheduler.pools_can_admit``): an exhausted proxy pool defers
         admission independently of the generator pool.
 
-        Returns one dict per request (in request order): the pre-refactor
-        keys (``reasoning_tokens``, ``n_reasoning``, ``ended_think``, and —
-        when ``answer_len`` > 0 — the greedy forced-answer
-        ``answer_tokens``) plus the request metadata: ``exit_reason``
-        (``eat`` / ``end_think`` / ``budget``), terminal ``status``,
-        per-request ``latency_s``, and — with ``record_trace`` — the
+        Returns a ``ServeResults``: one dict per request (in request
+        order), the pre-refactor keys (``reasoning_tokens``,
+        ``n_reasoning``, ``ended_think``, and — when ``answer_len`` > 0 —
+        the greedy forced-answer ``answer_tokens``) plus the request
+        metadata: ``exit_reason`` (``eat`` / ``end_think`` / ``budget``),
+        terminal ``status``, per-request ``latency_s``, ``queue_s`` and
+        ``first_token_s``, and — with ``record_trace`` — the
         chunk-boundary ``eat_trace`` (n_reasoning, n_evals, ema_var)
-        snapshots.
+        snapshots.  It is a ``list``; its ``telemetry`` holds the call's
+        host spans and counters (``serving.telemetry``).
 
         With ``overlap=True`` the loop is the double-buffered pipeline of
         ``serving.pipeline``: chunk N+1 is dispatched before chunk N's
@@ -445,25 +477,33 @@ class ReasoningEngine:
         samples).  ``pipeline_hooks`` (a ``serving.pipeline.PipelineHooks``)
         is the test seam for forcing adversarial interleavings.
         """
-        ss = self._serve_setup(prompts, prompt_len, rng,
-                               batch_size=batch_size, max_tokens=max_tokens,
-                               use_monitor=use_monitor, chunk_len=chunk_len,
-                               overlap=overlap)
-        if overlap:
-            from repro.serving.pipeline import serve_overlapped
-            try:
-                return serve_overlapped(self, ss, answer_len=answer_len,
-                                        record_trace=record_trace,
-                                        hooks=pipeline_hooks)
-            finally:
-                if ss.ptier is not None:
-                    # drop the proxy tier's device buffers; host-side
-                    # allocator stats stay readable via ``_ptier``
-                    ss.ptier.state = None
-        # ---- synchronous loop (--overlap off): one host round trip per
-        # chunk boundary.  The overlapped loop must stay bit-exact with
-        # this body under greedy sampling — change them together.
-        sched, state, rng = ss.sched, ss.state, ss.rng
+        tel = Telemetry()
+        n_req = int(np.shape(prompts)[0])
+        with tel.span("serve", loop="overlap" if overlap else "sync",
+                      slots=min(batch_size, n_req), requests=n_req):
+            ss = self._serve_setup(prompts, prompt_len, rng,
+                                   batch_size=batch_size,
+                                   max_tokens=max_tokens,
+                                   use_monitor=use_monitor,
+                                   chunk_len=chunk_len, tel=tel,
+                                   overlap=overlap)
+            if overlap:
+                from repro.serving.pipeline import serve_overlapped
+                cur_end = serve_overlapped(self, ss, answer_len=answer_len,
+                                           record_trace=record_trace,
+                                           hooks=pipeline_hooks)
+            else:
+                cur_end = self._serve_sync(ss, answer_len=answer_len,
+                                           record_trace=record_trace)
+            return self._serve_finish(ss, cur_end)
+
+    def _serve_sync(self, ss: SimpleNamespace, *, answer_len: int,
+                    record_trace: bool) -> int:
+        """The synchronous loop (--overlap off): one host round trip per
+        chunk boundary.  The overlapped loop must stay bit-exact with this
+        body under greedy sampling — change them together.  Every blocking
+        read goes through ``tel.fetch``.  Returns the final ring pointer."""
+        sched, state, rng, tel = ss.sched, ss.state, ss.rng, ss.tel
         alloc, ptier, paged = ss.alloc, ss.ptier, ss.paged
         proxy_mode, gen_monitor = ss.proxy_mode, ss.gen_monitor
         S, budget_dev = ss.S, ss.budget_dev
@@ -473,13 +513,20 @@ class ReasoningEngine:
         def ensure_pages(span: int, *, clamp_to_budget: bool = False):
             """Occupied-slot pages for the next generator dispatch — the
             shared sizing rule lives in ``Executor.ensure_chunk_pages``."""
-            return self.executor.ensure_chunk_pages(
-                alloc, state, [s for s, _ in sched.bound()], span,
-                tail=gen_tail, budget=budget if clamp_to_budget else None,
-            )
+            with tel.span("serve.pages", pool="gen"):
+                if clamp_to_budget:
+                    cur, n_r = tel.fetch("pages", (state.cache["cur"],
+                                                   state.n_reasoning))
+                else:
+                    cur, n_r = tel.fetch("pages", state.cache["cur"]), None
+                return self.executor.ensure_chunk_pages(
+                    alloc, state, [s for s, _ in sched.bound()], span,
+                    cur=int(cur), tail=gen_tail, n_reasoning=n_r,
+                    budget=budget if clamp_to_budget else None,
+                )
 
         while sched.running:
-            if bool(state.active.any()):
+            if bool(tel.fetch("any_active", state.active.any())):
                 if paged:
                     # a chunk writes <= chunk_len decode tokens (fewer for
                     # rows near their budget), each probe another
@@ -487,69 +534,81 @@ class ReasoningEngine:
                     state = ensure_pages(chunk_py + gen_tail,
                                          clamp_to_budget=True)
                 # host copy BEFORE the dispatch: the chunk donates ``state``
-                n_start = np.asarray(state.out_len) if proxy_mode else None
-                state = self.executor.decode_chunk(
-                    self.params, state, budget_dev, chunk,
-                    use_monitor=gen_monitor,
-                )
+                n_start = (tel.fetch("out_len", state.out_len)
+                           if proxy_mode else None)
+                with tel.span("serve.dispatch"):
+                    state = self.executor.decode_chunk(
+                        self.params, state, budget_dev, chunk,
+                        use_monitor=gen_monitor,
+                    )
+                tel.count("chunks")
                 if proxy_mode:
                     # shadow the chunk through the proxy, then reconcile:
                     # rewind overshoot rows to the proxy's exit step and
                     # sync its monitor into the state (executor.retract)
-                    n_emitted = np.asarray(state.out_len) - n_start
-                    ptier.begin_chunk(chunk_py,
-                                      [s for s, _ in sched.bound()])
-                    new_n, pmon = ptier.observe(
-                        self._across_tiers(state.out_tokens), n_start,
-                        n_emitted, chunk_py,
-                    )
-                    state = self.executor.retract(
-                        state, self._across_tiers(new_n),
-                        self._across_tiers(pmon),
-                    )
-            active_np = np.asarray(state.active)
+                    with tel.span("serve.proxy"):
+                        n_emitted = tel.fetch("out_len",
+                                              state.out_len) - n_start
+                        ptier.begin_chunk(chunk_py,
+                                          [s for s, _ in sched.bound()])
+                        new_n, pmon = ptier.observe(
+                            self._across_tiers(state.out_tokens, tel),
+                            n_start, n_emitted, chunk_py,
+                        )
+                        state = self.executor.retract(
+                            state, self._across_tiers(new_n, tel),
+                            self._across_tiers(pmon, tel),
+                        )
+            active_np = tel.fetch("active", state.active)
+            t_seen = time.perf_counter()
+            for _, req in sched.bound():
+                req.saw_tokens(t_seen)
             if record_trace:
-                n_np = np.asarray(state.n_reasoning)
-                ev_np = np.asarray(state.monitor.n_evals)
-                var_np = np.asarray(
-                    self.monitor.stopper.debiased_var(state.monitor.stop_state)
-                )
-                for s, req in sched.bound():
-                    req.record_trace(n_np[s], ev_np[s], var_np[s])
+                with tel.span("serve.trace"):
+                    n_np, ev_np, var_np = tel.fetch("trace", (
+                        state.n_reasoning, state.monitor.n_evals,
+                        self.monitor.stopper.debiased_var(
+                            state.monitor.stop_state)))
+                    for s, req in sched.bound():
+                        req.record_trace(n_np[s], ev_np[s], var_np[s])
             done = sched.finished_slots(active_np)
             if not done:
                 continue
             # harvest results (answers roll out from the still-intact cache
             # rows) BEFORE any slot is overwritten by an admission
-            ans = None
-            if answer_len:
-                if paged:
-                    # a rollout writes </think> + answer_len slots past cur
-                    state = ensure_pages(answer_len + 1)
-                toks, _ = self.force_answer(state, answer_len, greedy=True)
-                ans = np.asarray(toks)
-            out_tokens = np.asarray(state.out_tokens)
-            out_len = np.asarray(state.out_len)
-            n_reasoning = np.asarray(state.n_reasoning)
-            ended = np.asarray(state.ended_think)
-            eat_stop = np.asarray(state.monitor.stop_flag)
-            for s, req in done:
-                sched.release(s)
-                req.finish(
-                    reasoning_tokens=out_tokens[s, :out_len[s]].copy(),
-                    n_reasoning=int(n_reasoning[s]),
-                    ended_think=bool(ended[s]),
-                    eat_stop=bool(eat_stop[s]),
-                    answer_tokens=ans[s].copy() if ans is not None else None,
-                )
-                if paged:
-                    # reclaim the moment a request exits: these pages back
-                    # the admissions below, in the same batch
-                    alloc.free_row(s)
-                if ptier is not None:
-                    # the proxy's shadow pages are reclaimed in the same
-                    # breath — a proxy-driven exit frees BOTH pools
-                    ptier.free_row(s)
+            with tel.span("serve.harvest"):
+                ans = None
+                if answer_len:
+                    if paged:
+                        # a rollout writes </think> + answer_len slots
+                        # past cur
+                        state = ensure_pages(answer_len + 1)
+                    toks, _ = self.force_answer(state, answer_len,
+                                                greedy=True)
+                    ans = tel.fetch("answer", toks)
+                out_tokens, out_len, n_reasoning, ended, eat_stop = \
+                    tel.fetch("harvest", (state.out_tokens, state.out_len,
+                                          state.n_reasoning,
+                                          state.ended_think,
+                                          state.monitor.stop_flag))
+                for s, req in done:
+                    sched.release(s)
+                    req.finish(
+                        reasoning_tokens=out_tokens[s, :out_len[s]].copy(),
+                        n_reasoning=int(n_reasoning[s]),
+                        ended_think=bool(ended[s]),
+                        eat_stop=bool(eat_stop[s]),
+                        answer_tokens=(ans[s].copy() if ans is not None
+                                       else None),
+                    )
+                    if paged:
+                        # reclaim the moment a request exits: these pages
+                        # back the admissions below, in the same batch
+                        alloc.free_row(s)
+                    if ptier is not None:
+                        # the proxy's shadow pages are reclaimed in the
+                        # same breath — a proxy-driven exit frees BOTH pools
+                        ptier.free_row(s)
             # admission sweeps EVERY free slot, not just this round's
             # harvested ones: a paged admission deferred earlier (pool
             # momentarily full) left its slot empty, and the pages freed
@@ -565,7 +624,7 @@ class ReasoningEngine:
                 # applies to BOTH backends (paged keeps ring addressing);
                 # the paged page check DEFERS instead of refusing — the
                 # request stays queued until an exit frees enough pages.
-                sched.check_capacity(int(state.cache["cur"]),
+                sched.check_capacity(int(tel.fetch("cur", state.cache["cur"])),
                                      "another admission")
                 if ptier is not None:
                     ptier.check_capacity("another admission")
@@ -580,20 +639,22 @@ class ReasoningEngine:
                             a.deferrals += 1
                     continue
                 nxt = sched.admit_next(s)
-                rng, sub = jax.random.split(rng)
-                one = self.start(jnp.asarray(nxt.prompt[None]),
-                                 jnp.asarray([nxt.prompt_len]), sub,
-                                 capacity=C_pre if paged else None)
-                if paged:
-                    row_table = alloc.admit_row(s, S,
-                                                int(state.cache["cur"]))
-                    state = self.executor.admit_paged(state, one, s,
-                                                      row_table)
-                else:
-                    state = self._admit(state, one, s)
-                if ptier is not None:
-                    ptier.admit(s, nxt.prompt, nxt.prompt_len, S)
-                nxt.begin_decode()
+                with tel.span("serve.admit", rid=nxt.rid):
+                    rng, sub = jax.random.split(rng)
+                    one = self.start(jnp.asarray(nxt.prompt[None]),
+                                     jnp.asarray([nxt.prompt_len]), sub,
+                                     capacity=C_pre if paged else None)
+                    if paged:
+                        cur = int(tel.fetch("cur", state.cache["cur"]))
+                        row_table = alloc.admit_row(s, S, cur)
+                        state = self.executor.admit_paged(state, one, s,
+                                                          row_table)
+                    else:
+                        state = self._admit(state, one, s)
+                    if ptier is not None:
+                        ptier.admit(s, nxt.prompt, nxt.prompt_len, S)
+                    nxt.begin_decode()
+                tel.count("admissions")
             if sched.pending and not sched.running:
                 # every slot is empty yet the queue cannot drain — name the
                 # pool that is actually too small to hold one request
@@ -613,12 +674,7 @@ class ReasoningEngine:
                         f"{ptier.alloc.blocks_for(S) + 1} pages. "
                         f"Raise ProxyConfig.cache.num_pages."
                     )
-        if ptier is not None:
-            # drop the proxy tier's device buffers (its KV cache/pool is
-            # the tier's largest allocation); the host-side allocator
-            # stats stay readable via ``_ptier`` for tests and benches
-            ptier.state = None
-        return [r.to_result() for r in ss.requests]
+        return int(tel.fetch("cur", state.cache["cur"]))
 
     # ------------------------------------------------------------- answers
     def force_answer(self, state: ServeState, n_tokens: int, rng=None,

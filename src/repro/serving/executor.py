@@ -49,6 +49,11 @@ NO probe program and no monitored chunk — the black-box contract: no
 generator logits feed the exit decision (audited by key inspection on
 ``_programs`` in tests/test_proxy_serve.py).
 
+Every jitted function is named after what it does (``decode_chunk``,
+``rollout``, ``observe_chunk`` ...), so its lowered module — and the
+device trace's ``XLA Modules`` line — reads ``jit_<name>``, one name per
+program family (tests/test_audit.py).
+
 Programs are built once per ``(batch, variant)`` and cached.  With a mesh
 in ``model.ctx`` (threaded from ``launch.mesh``) every program is jitted
 with explicit ``in_shardings``/``out_shardings`` derived from
@@ -379,7 +384,7 @@ def build_stream_monitor_programs(model: Model, probe: ProbeSpec):
         return positions_for(model.cfg, pos1d)
 
     @jax.jit
-    def consume(params, cache, tokens, next_pos):
+    def stream_consume(params, cache, tokens, next_pos):
         B, m = tokens.shape
         pos1d = next_pos[:, None] + jnp.arange(m, dtype=jnp.int32)[None]
         _, cache = model.prefill(params, tokens, _positions(pos1d), pos1d,
@@ -387,14 +392,14 @@ def build_stream_monitor_programs(model: Model, probe: ProbeSpec):
         return cache, next_pos + m
 
     @jax.jit
-    def probe_fn(params, cache, next_pos):
+    def stream_probe(params, cache, next_pos):
         return eval_eat(model, params, cache, probe, next_pos)
 
     @jax.jit
-    def prefill(params, prompts, positions, pos1d, cache):
+    def stream_prefill(params, prompts, positions, pos1d, cache):
         return model.prefill(params, prompts, positions, pos1d, cache)
 
-    return consume, probe_fn, prefill
+    return stream_consume, stream_probe, stream_prefill
 
 
 # --------------------------------------------------------------------------
@@ -498,7 +503,7 @@ class Executor:
         if key not in self._programs:
             step_fn = self._step_mon if use_monitor else self._step_plain
 
-            def chunk(params, st: ServeState, budget, chunk_len):
+            def decode_chunk(params, st: ServeState, budget, chunk_len):
                 def cond(carry):
                     i, s = carry
                     return (i < chunk_len) & s.active.any()
@@ -514,11 +519,11 @@ class Executor:
 
             dn = (1,) if donate else ()
             if self.ctx.mesh is None:
-                jitted = jax.jit(chunk, donate_argnums=dn)
+                jitted = jax.jit(decode_chunk, donate_argnums=dn)
             else:
                 ssh = self._state_sh(state)
                 jitted = jax.jit(
-                    chunk,
+                    decode_chunk,
                     in_shardings=(self._param_sh, ssh, self._ns(P()),
                                   self._ns(P())),
                     out_shardings=ssh,
@@ -569,7 +574,8 @@ class Executor:
         if key not in self._programs:
             step_fn = self._step_mon if use_monitor else self._step_plain
 
-            def chunk(params, st: ServeState, budget, chunk_len):
+            def decode_chunk_snapshot(params, st: ServeState, budget,
+                                      chunk_len):
                 def cond(carry):
                     i, s = carry
                     return (i < chunk_len) & s.active.any()
@@ -584,11 +590,11 @@ class Executor:
                 return st, self._snapshot_of(st)
 
             if self.ctx.mesh is None:
-                jitted = jax.jit(chunk, donate_argnums=(1,))
+                jitted = jax.jit(decode_chunk_snapshot, donate_argnums=(1,))
             else:
                 ssh = self._state_sh(state)
                 jitted = jax.jit(
-                    chunk,
+                    decode_chunk_snapshot,
                     in_shardings=(self._param_sh, ssh, self._ns(P()),
                                   self._ns(P())),
                     out_shardings=(ssh,
@@ -616,15 +622,16 @@ class Executor:
     def decode_program(self, state: ServeState):
         key = ("decode", int(state.active.shape[0]), self._kind(state.cache))
         if key not in self._programs:
-            def fn(params, st: ServeState):
+            def decode_step(params, st: ServeState):
                 no_budget = jnp.asarray(jnp.iinfo(jnp.int32).max, jnp.int32)
                 return self._advance(params, st, no_budget, self._step_plain)
 
             if self.ctx.mesh is None:
-                jitted = jax.jit(fn)
+                jitted = jax.jit(decode_step)
             else:
                 ssh = self._state_sh(state)
-                jitted = jax.jit(fn, in_shardings=(self._param_sh, ssh),
+                jitted = jax.jit(decode_step,
+                                 in_shardings=(self._param_sh, ssh),
                                  out_shardings=ssh)
             self._programs[key] = jitted
         return self._programs[key]
@@ -643,20 +650,21 @@ class Executor:
             model = self.model
 
             if has_frames:
-                def fn(params, tokens, positions, pos1d, cache, frames):
+                def prefill(params, tokens, positions, pos1d, cache, frames):
                     return model.prefill(params, tokens, positions, pos1d,
                                          cache, frames=frames)
             elif has_image:
-                def fn(params, tokens, positions, pos1d, cache, image_embeds):
+                def prefill(params, tokens, positions, pos1d, cache,
+                            image_embeds):
                     return model.prefill(params, tokens, positions, pos1d,
                                          cache, image_embeds=image_embeds)
             else:
-                def fn(params, tokens, positions, pos1d, cache):
+                def prefill(params, tokens, positions, pos1d, cache):
                     return model.prefill(params, tokens, positions, pos1d,
                                          cache)
 
             if self.ctx.mesh is None:
-                jitted = jax.jit(fn, donate_argnums=4)
+                jitted = jax.jit(prefill, donate_argnums=4)
             else:
                 b = self._batch_entry(B)
                 pos_spec = (P(b, None, None) if self.cfg.mrope_sections
@@ -670,7 +678,7 @@ class Executor:
                 ]
                 if has_frames or has_image:
                     in_sh.append(self._ns(P(b, None, None)))
-                jitted = jax.jit(fn, in_shardings=tuple(in_sh),
+                jitted = jax.jit(prefill, in_shardings=tuple(in_sh),
                                  donate_argnums=4)
             self._programs[key] = jitted
         return self._programs[key]
@@ -690,14 +698,14 @@ class Executor:
         if key not in self._programs:
             model, monitor = self.model, self.monitor
 
-            def fn(params, cache, next_pos):
+            def probe(params, cache, next_pos):
                 return eval_eat(model, params, cache, monitor.probe, next_pos)
 
             if self.ctx.mesh is None:
-                jitted = jax.jit(fn)
+                jitted = jax.jit(probe)
             else:
                 b = self._batch_entry(B)
-                jitted = jax.jit(fn, in_shardings=(
+                jitted = jax.jit(probe, in_shardings=(
                     self._param_sh,
                     self._sh(cache_pspecs(self.cfg, self.ctx, cache)),
                     self._ns(P(b)),
@@ -715,7 +723,8 @@ class Executor:
     def admit_program(self, state: ServeState, one: ServeState):
         key = ("admit", int(state.active.shape[0]))
         if key not in self._programs:
-            def fn(state: ServeState, one: ServeState, slot) -> ServeState:
+            def admit(state: ServeState, one: ServeState, slot
+                      ) -> ServeState:
                 def put(big, small):
                     return big.at[slot].set(small[0])
 
@@ -734,11 +743,11 @@ class Executor:
                 )
 
             if self.ctx.mesh is None:
-                jitted = jax.jit(fn, donate_argnums=0)
+                jitted = jax.jit(admit, donate_argnums=0)
             else:
                 ssh = self._state_sh(state)
                 jitted = jax.jit(
-                    fn,
+                    admit,
                     in_shardings=(ssh, self._state_sh(one), self._ns(P())),
                     out_shardings=ssh,
                     donate_argnums=0,
@@ -762,11 +771,14 @@ class Executor:
         C_pre = int(dense_cache["pos"].shape[1])
         key = ("pack", B, C_pre)
         if key not in self._programs:
+            def pack_paged(paged_cache, dense_cache, table):
+                return pack_paged_cache(paged_cache, dense_cache, table)
+
             if self.ctx.mesh is None:
-                jitted = jax.jit(pack_paged_cache, donate_argnums=0)
+                jitted = jax.jit(pack_paged, donate_argnums=0)
             else:
                 jitted = jax.jit(
-                    pack_paged_cache,
+                    pack_paged,
                     in_shardings=(
                         self._sh(cache_pspecs(self.cfg, self.ctx, paged_cache)),
                         self._sh(cache_pspecs(self.cfg, self.ctx, dense_cache)),
@@ -792,8 +804,8 @@ class Executor:
         key = ("admit", int(state.active.shape[0]), "paged",
                int(one.cache["pos"].shape[1]))
         if key not in self._programs:
-            def fn(state: ServeState, one: ServeState, slot,
-                   row_table) -> ServeState:
+            def admit_paged(state: ServeState, one: ServeState, slot,
+                            row_table) -> ServeState:
                 def put(big, small):
                     return big.at[slot].set(small[0])
 
@@ -813,11 +825,11 @@ class Executor:
                 )
 
             if self.ctx.mesh is None:
-                jitted = jax.jit(fn, donate_argnums=0)
+                jitted = jax.jit(admit_paged, donate_argnums=0)
             else:
                 ssh = self._state_sh(state)
                 jitted = jax.jit(
-                    fn,
+                    admit_paged,
                     in_shardings=(ssh, self._state_sh(one), self._ns(P()),
                                   self._ns(P(None))),
                     out_shardings=ssh,
@@ -914,7 +926,8 @@ class Executor:
         if key not in self._programs:
             ecfg = self.ecfg
 
-            def fn(state: ServeState, new_n, pmon: MonitorState) -> ServeState:
+            def retract(state: ServeState, new_n, pmon: MonitorState
+                        ) -> ServeState:
                 overshoot = state.n_reasoning - new_n
                 next_pos = state.next_pos - overshoot
                 cache = dict(state.cache)
@@ -945,12 +958,12 @@ class Executor:
                 )
 
             if self.ctx.mesh is None:
-                jitted = jax.jit(fn, donate_argnums=0)
+                jitted = jax.jit(retract, donate_argnums=0)
             else:
                 ssh = self._state_sh(state)
                 b = self._batch_entry(int(state.active.shape[0]))
                 jitted = jax.jit(
-                    fn,
+                    retract,
                     in_shardings=(
                         ssh,
                         self._ns(P(b)),
@@ -992,7 +1005,8 @@ class Executor:
         if key not in self._programs:
             ecfg = self.ecfg
 
-            def fn(state: ServeState, new_n, pmon: MonitorState) -> ServeState:
+            def retract_lagged(state: ServeState, new_n, pmon: MonitorState
+                               ) -> ServeState:
                 stop = pmon.stop_flag
                 # only proxy-STOPPED rows rewind: the others have already
                 # decoded one more chunk whose tokens the proxy has not
@@ -1025,12 +1039,12 @@ class Executor:
                 )
 
             if self.ctx.mesh is None:
-                jitted = jax.jit(fn, donate_argnums=0)
+                jitted = jax.jit(retract_lagged, donate_argnums=0)
             else:
                 ssh = self._state_sh(state)
                 b = self._batch_entry(int(state.active.shape[0]))
                 jitted = jax.jit(
-                    fn,
+                    retract_lagged,
                     in_shardings=(
                         ssh,
                         self._ns(P(b)),
@@ -1066,7 +1080,7 @@ class Executor:
             def positions(pos1d):
                 return positions_for(cfg, pos1d)
 
-            def fn(params, cache, next_pos, last_token, rng):
+            def rollout(params, cache, next_pos, last_token, rng):
                 et = jnp.full((B, 1), ecfg.end_think_id, jnp.int32)
                 pos1d = next_pos[:, None]
                 logits, cache2 = model.decode_step(
@@ -1092,10 +1106,10 @@ class Executor:
                 return jnp.moveaxis(toks, 0, 1), jnp.moveaxis(lps, 0, 1)
 
             if self.ctx.mesh is None:
-                jitted = jax.jit(fn)
+                jitted = jax.jit(rollout)
             else:
                 b = self._batch_entry(B)
-                jitted = jax.jit(fn, in_shardings=(
+                jitted = jax.jit(rollout, in_shardings=(
                     self._param_sh,
                     self._sh(cache_pspecs(self.cfg, self.ctx, cache)),
                     self._ns(P(b)),
@@ -1173,8 +1187,8 @@ class ProxyExecutor(Executor):
         if key not in self._programs:
             shadow = self._shadow
 
-            def fn(params, st: ServeState, toks, n_start, n_emitted,
-                   chunk_len) -> ServeState:
+            def observe_chunk(params, st: ServeState, toks, n_start,
+                              n_emitted, chunk_len) -> ServeState:
                 def valid_of(s, i):
                     return (i < n_emitted) & ~s.monitor.stop_flag
 
@@ -1211,12 +1225,12 @@ class ProxyExecutor(Executor):
                 return st
 
             if self.ctx.mesh is None:
-                jitted = jax.jit(fn, donate_argnums=1)
+                jitted = jax.jit(observe_chunk, donate_argnums=1)
             else:
                 ssh = self._state_sh(pstate)
                 tok_sp, row_sp = proxy_stream_pspecs(self.ctx, B)
                 jitted = jax.jit(
-                    fn,
+                    observe_chunk,
                     in_shardings=(self._param_sh, ssh, self._ns(tok_sp),
                                   self._ns(row_sp), self._ns(row_sp),
                                   self._ns(P())),

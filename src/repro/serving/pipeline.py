@@ -40,10 +40,13 @@ Host-side consistency is the job of two pieces of pure-host bookkeeping:
 
 Layering contract (enforced by ``tools/audit``): this module is DISPATCH
 ONLY — it builds no jitted programs (executor-only-jit) and never calls
-``jax.block_until_ready`` / ``device_get``; the single sanctioned blocking
-read is ``np.asarray`` on a *snapshot* (never on donated state).
+``jax.block_until_ready`` / ``device_get``; the sanctioned blocking reads
+are ``Telemetry.fetch`` (``np.asarray`` in a ``serve.wait`` span) on a
+*snapshot* or on the proxy chain's outputs (never on donated state).
 """
 from __future__ import annotations
+
+import time
 
 import jax
 import jax.numpy as jnp
@@ -90,13 +93,16 @@ class PipelineHooks:
 
 def serve_overlapped(engine, ss, *, answer_len: int = 0,
                      record_trace: bool = False,
-                     hooks: PipelineHooks | None = None) -> list[dict]:
+                     hooks: PipelineHooks | None = None) -> int:
     """The overlapped serve loop body.  ``ss`` is the namespace from
     ``ReasoningEngine._serve_setup`` (prefilled initial cohort, scheduler,
-    allocators, proxy tier); results are identical in shape and — under
-    greedy sampling — in content to the sync loop's."""
+    allocators, proxy tier, the call's telemetry); the requests it
+    finishes are identical — under greedy sampling — to the sync loop's.
+    Returns the ring pointer of the last retired boundary for the engine's
+    shared finaliser."""
     ex = engine.executor
     ecfg = engine.ecfg
+    tel = ss.tel
     sched, alloc, ptier = ss.sched, ss.alloc, ss.ptier
     paged, proxy_mode = ss.paged, ss.proxy_mode
     S, B, budget, chunk_py = ss.S, ss.B, ss.budget, ss.chunk_py
@@ -121,16 +127,19 @@ def serve_overlapped(engine, ss, *, answer_len: int = 0,
         nonlocal state
         bound = [(s, r) for s, r in sched.bound()]
         if paged:
-            state = ex.ensure_chunk_pages(
-                alloc, state, [s for s, _ in bound], chunk_py + ss.gen_tail,
-                tail=ss.gen_tail, budget=budget, cur=mirror_cur,
-                n_reasoning=mirror_nr,
-                slack=chunk_py if ledger.in_flight else 0,
+            with tel.span("serve.pages", pool="gen"):
+                state = ex.ensure_chunk_pages(
+                    alloc, state, [s for s, _ in bound],
+                    chunk_py + ss.gen_tail, tail=ss.gen_tail, budget=budget,
+                    cur=mirror_cur, n_reasoning=mirror_nr,
+                    slack=chunk_py if ledger.in_flight else 0,
+                )
+        with tel.span("serve.dispatch"):
+            state, snap = ex.decode_chunk_snapshot(
+                engine.params, state, ss.budget_dev, ss.chunk,
+                use_monitor=ss.gen_monitor,
             )
-        state, snap = ex.decode_chunk_snapshot(
-            engine.params, state, ss.budget_dev, ss.chunk,
-            use_monitor=ss.gen_monitor,
-        )
+        tel.count("chunks")
         fence = ledger.open_fence()
         hooks.on_dispatch(fence, snap)
         return fence, snap, bound
@@ -139,9 +148,9 @@ def serve_overlapped(engine, ss, *, answer_len: int = 0,
         """Read boundary ``fence``'s snapshot (blocks only on that chunk),
         reconcile, harvest, and admit — all while the next chunk flies."""
         nonlocal state, rng, mirror_cur
-        ints = np.asarray(snap["ints"])
-        var_np = np.asarray(snap["var"])
-        toks = np.asarray(snap["tokens"])[:, :-1]
+        ints, var_np, toks = tel.fetch(
+            "snapshot", (snap["ints"], snap["var"], snap["tokens"]))
+        toks = toks[:, :-1]
         active_np = ints[SNAP_ACTIVE].astype(bool)
         nr = ints[SNAP_NR]
         outlen = ints[SNAP_OUTLEN]
@@ -154,6 +163,10 @@ def serve_overlapped(engine, ss, *, answer_len: int = 0,
         # slots re-admitted while this chunk flew: their snapshot rows are
         # the PREVIOUS occupant's — ignore them everywhere below
         skip = ledger.admitted_after(fence)
+        t_seen = time.perf_counter()
+        for s, req in bound:
+            if s not in skip:
+                req.saw_tokens(t_seen)
 
         new_n = pstop = pevals = pvar = None
         if proxy_mode:
@@ -161,31 +174,33 @@ def serve_overlapped(engine, ss, *, answer_len: int = 0,
             # its own dispatch chain — concurrent with the generator's
             # in-flight chunk), then reconcile the generator ONE boundary
             # late: only proxy-stopped rows rewind (retract_lagged)
-            n_start = mirror_outlen.copy()
-            n_emitted = (outlen - n_start).astype(np.int32)
-            for s in skip:
-                n_emitted[s] = 0
-            ptier.begin_chunk(chunk_py, [s for s, _ in sched.bound()])
-            new_n_dev, pmon = ptier.observe(toks, n_start, n_emitted,
-                                            chunk_py)
-            new_n = np.asarray(new_n_dev)
-            pstop = np.asarray(pmon.stop_flag).astype(bool)
-            pevals = np.asarray(pmon.n_evals)
-            pvar = np.asarray(
-                engine.monitor.stopper.debiased_var(pmon.stop_state))
-            hooks.on_observe(fence, ptier.state)
-            state = ex.retract_lagged(state, engine._across_tiers(new_n_dev),
-                                      engine._across_tiers(pmon))
-            hooks.on_retract(fence)
+            with tel.span("serve.proxy"):
+                n_start = mirror_outlen.copy()
+                n_emitted = (outlen - n_start).astype(np.int32)
+                for s in skip:
+                    n_emitted[s] = 0
+                ptier.begin_chunk(chunk_py, [s for s, _ in sched.bound()])
+                new_n_dev, pmon = ptier.observe(toks, n_start, n_emitted,
+                                                chunk_py)
+                new_n, pstop, pevals, pvar = tel.fetch("proxy", (
+                    new_n_dev, pmon.stop_flag, pmon.n_evals,
+                    engine.monitor.stopper.debiased_var(pmon.stop_state)))
+                pstop = pstop.astype(bool)
+                hooks.on_observe(fence, ptier.state)
+                state = ex.retract_lagged(
+                    state, engine._across_tiers(new_n_dev, tel),
+                    engine._across_tiers(pmon, tel))
+                hooks.on_retract(fence)
 
         if record_trace:
             # ``bound`` was captured at dispatch — exactly the rows that
             # decoded this chunk; already-finished requests self-guard
-            for s, req in bound:
-                if proxy_mode:
-                    req.record_trace(new_n[s], pevals[s], pvar[s])
-                else:
-                    req.record_trace(nr[s], evals[s], var_np[s])
+            with tel.span("serve.trace"):
+                for s, req in bound:
+                    if proxy_mode:
+                        req.record_trace(new_n[s], pevals[s], pvar[s])
+                    else:
+                        req.record_trace(nr[s], evals[s], var_np[s])
 
         if proxy_mode:
             active_eff = active_np & ~pstop
@@ -194,49 +209,56 @@ def serve_overlapped(engine, ss, *, answer_len: int = 0,
         done = [(s, r) for s, r in sched.finished_slots(active_eff)
                 if s not in skip]
 
-        ans = None
-        if answer_len and done:
-            if paged:
-                # rollout writes </think> + answer_len slots past cur; the
-                # in-flight chunk may already have advanced the ring, so
-                # over-map by one chunk of slack
-                state = ex.ensure_chunk_pages(
-                    alloc, state, [s for s, _ in sched.bound()],
-                    answer_len + 1, cur=cur,
-                    slack=chunk_py if ledger.in_flight else 0,
-                )
-            toks_ans, _ = engine.force_answer(state, answer_len, greedy=True)
-            ans = np.asarray(toks_ans)
-
-        for s, req in done:
-            sched.release(s)
-            ledger.mark_released(s, fence)
-            if proxy_mode:
-                n_fin = int(new_n[s]) if pstop[s] else int(nr[s])
-                eat_s = bool(pstop[s])
-                # recompute off the truncated stream — the snapshot's flag
-                # may predate the lagged rewind
-                ended_s = bool((toks[s, :n_fin] == ecfg.end_think_id).any())
-            else:
-                n_fin = int(nr[s])
-                eat_s = bool(stop[s])
-                ended_s = bool(ended[s])
-            req.finish(
-                reasoning_tokens=toks[s, :n_fin].copy(),
-                n_reasoning=n_fin,
-                ended_think=ended_s,
-                eat_stop=eat_s,
-                answer_tokens=ans[s].copy() if ans is not None else None,
-            )
-            if paged:
-                # the in-flight chunk's page table still maps this row's
-                # pages: park them on the ledger until its fence retires
-                ledger.defer_free(alloc, s)
-            if ptier is not None:
-                # the proxy chain was synced by the observe read above —
-                # its pages can go straight back to the pool
-                ptier.free_row(s)
         if done:
+            with tel.span("serve.harvest"):
+                ans = None
+                if answer_len:
+                    if paged:
+                        # rollout writes </think> + answer_len slots past
+                        # cur; the in-flight chunk may already have
+                        # advanced the ring, so over-map by one chunk of
+                        # slack
+                        with tel.span("serve.pages", pool="gen"):
+                            state = ex.ensure_chunk_pages(
+                                alloc, state, [s for s, _ in sched.bound()],
+                                answer_len + 1, cur=cur,
+                                slack=chunk_py if ledger.in_flight else 0,
+                            )
+                    toks_ans, _ = engine.force_answer(state, answer_len,
+                                                      greedy=True)
+                    ans = tel.fetch("answer", toks_ans)
+
+                for s, req in done:
+                    sched.release(s)
+                    ledger.mark_released(s, fence)
+                    if proxy_mode:
+                        n_fin = int(new_n[s]) if pstop[s] else int(nr[s])
+                        eat_s = bool(pstop[s])
+                        # recompute off the truncated stream — the
+                        # snapshot's flag may predate the lagged rewind
+                        ended_s = bool(
+                            (toks[s, :n_fin] == ecfg.end_think_id).any())
+                    else:
+                        n_fin = int(nr[s])
+                        eat_s = bool(stop[s])
+                        ended_s = bool(ended[s])
+                    req.finish(
+                        reasoning_tokens=toks[s, :n_fin].copy(),
+                        n_reasoning=n_fin,
+                        ended_think=ended_s,
+                        eat_stop=eat_s,
+                        answer_tokens=(ans[s].copy() if ans is not None
+                                       else None),
+                    )
+                    if paged:
+                        # the in-flight chunk's page table still maps this
+                        # row's pages: park them on the ledger until its
+                        # fence retires
+                        ledger.defer_free(alloc, s)
+                    if ptier is not None:
+                        # the proxy chain was synced by the observe read
+                        # above — its pages can go straight back to the pool
+                        ptier.free_row(s)
             hooks.on_harvest(fence, [s for s, _ in done])
 
         # mirrors advance to this boundary's (post-verdict) values; skip
@@ -269,18 +291,20 @@ def serve_overlapped(engine, ss, *, answer_len: int = 0,
                         a.deferrals += 1
                 continue
             nxt = sched.admit_next(s)
-            rng, sub = jax.random.split(rng)
-            one = engine.start(jnp.asarray(nxt.prompt[None]),
-                               jnp.asarray([nxt.prompt_len]), sub,
-                               capacity=ss.C_pre)
-            if paged:
-                row_table = alloc.admit_row(s, S, used_ub)
-                state = ex.admit_paged(state, one, s, row_table)
-            else:
-                state = engine._admit(state, one, s)
-            if ptier is not None:
-                ptier.admit(s, nxt.prompt, nxt.prompt_len, S)
-            nxt.begin_decode()
+            with tel.span("serve.admit", rid=nxt.rid):
+                rng, sub = jax.random.split(rng)
+                one = engine.start(jnp.asarray(nxt.prompt[None]),
+                                   jnp.asarray([nxt.prompt_len]), sub,
+                                   capacity=ss.C_pre)
+                if paged:
+                    row_table = alloc.admit_row(s, S, used_ub)
+                    state = ex.admit_paged(state, one, s, row_table)
+                else:
+                    state = engine._admit(state, one, s)
+                if ptier is not None:
+                    ptier.admit(s, nxt.prompt, nxt.prompt_len, S)
+                nxt.begin_decode()
+            tel.count("admissions")
             nxt.admitted_fence = ledger.mark_admitted(s)
             mirror_nr[s] = mirror_outlen[s] = 1
             hooks.on_admit(ledger.fence, s)
@@ -324,4 +348,4 @@ def serve_overlapped(engine, ss, *, answer_len: int = 0,
             )
         break
 
-    return [r.to_result() for r in ss.requests]
+    return mirror_cur

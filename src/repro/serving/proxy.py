@@ -51,6 +51,7 @@ from repro.serving.executor import (
     positions_for,
 )
 from repro.serving.scheduler import PageAllocator
+from repro.serving.telemetry import Telemetry
 
 
 @dataclasses.dataclass
@@ -167,12 +168,16 @@ class ProxyTier:
     The tier never sees generator logits and never decides tokens — it
     consumes the emitted stream and returns exit decisions, which the
     engine applies through the generator executor's ``retract`` program.
+    Its blocking reads of the proxy state go through the serve call's
+    ``telemetry`` (``serve.wait`` spans), its page pushes run in
+    ``serve.pages`` spans of pool ``proxy``.
     """
 
     def __init__(self, executor: ProxyExecutor, params, ecfg,
                  monitor: ReasoningMonitor, cache_cfg: CacheConfig,
-                 capacity: int, budget: int):
+                 capacity: int, budget: int, telemetry: Telemetry):
         self.ex = executor
+        self.tel = telemetry
         self.params = params
         self.ecfg = ecfg
         self.monitor = monitor
@@ -248,10 +253,14 @@ class ProxyTier:
         the proxy's own pool and state."""
         if not self.paged:
             return
-        self.state = self.ex.ensure_chunk_pages(
-            self.alloc, self.state, bound, chunk_py + self.probe_m,
-            tail=self.probe_m, budget=self.budget,
-        )
+        with self.tel.span("serve.pages", pool="proxy"):
+            cur, n_r = self.tel.fetch("proxy_pages", (
+                self.state.cache["cur"], self.state.n_reasoning))
+            self.state = self.ex.ensure_chunk_pages(
+                self.alloc, self.state, bound, chunk_py + self.probe_m,
+                cur=int(cur), tail=self.probe_m, budget=self.budget,
+                n_reasoning=n_r,
+            )
 
     def observe(self, gen_out_tokens, n_start, n_emitted, chunk_py: int):
         """Shadow one generator chunk; returns ``(new_n, proxy monitor)``
@@ -279,7 +288,7 @@ class ProxyTier:
         capacity the scheduler's own guard always fires first)."""
         if self.paged:
             return
-        used = int(self.state.cache["cur"])
+        used = int(self.tel.fetch("proxy_cur", self.state.cache["cur"]))
         if used + self.budget > self.capacity:
             raise RuntimeError(
                 f"proxy cache capacity {self.capacity} cannot hold {when}: "
@@ -295,8 +304,8 @@ class ProxyTier:
                           jnp.asarray([prompt_len]),
                           self._C_pre if self.paged else self.capacity)
         if self.paged:
-            row_table = self.alloc.admit_row(slot, S,
-                                             int(self.state.cache["cur"]))
+            cur = int(self.tel.fetch("proxy_cur", self.state.cache["cur"]))
+            row_table = self.alloc.admit_row(slot, S, cur)
             self.state = self.ex.admit_paged(self.state, one, slot,
                                              row_table)
         else:

@@ -26,6 +26,14 @@ fence open when the slot was granted (the pipeline skips the row in that
 fence's snapshot — the data there belongs to the slot's previous
 occupant), and ``submitted_at``/``latency_s`` give the per-request latency
 the scaling benchmark reports as percentiles.
+
+Stamps (``time.perf_counter``): ``submitted_at`` when the serve call took
+the request, ``admitted_at`` when the scheduler granted it a slot, and
+``first_token_at`` at the first chunk boundary whose device->host read saw
+the row's tokens.  ``finish()`` turns them into ``latency_s``, ``queue_s``
+and ``first_token_s``, each from ``submitted_at``.  Under the overlapped
+loop's asynchronous dispatch ``admitted_at`` is when the host admitted the
+row, not when the device ran its prefill.
 """
 from __future__ import annotations
 
@@ -68,6 +76,8 @@ class Request:
     # wall-clock submission stamp (set by the serve loop's setup) — when
     # present, finish() derives result["latency_s"] from it
     submitted_at: Optional[float] = None
+    admitted_at: Optional[float] = None
+    first_token_at: Optional[float] = None
     # overlap-mode IN_FLIGHT bookkeeping: the dispatch fence open when the
     # slot was granted (see InFlightLedger.admitted_after)
     admitted_fence: Optional[int] = None
@@ -85,11 +95,17 @@ class Request:
         self._expect(RequestStatus.QUEUED)
         self.status = RequestStatus.PREFILLING
         self.slot = slot
+        self.admitted_at = time.perf_counter()
 
     def begin_decode(self) -> None:
         """PREFILLING -> DECODING: the prefilled row is live in the batch."""
         self._expect(RequestStatus.PREFILLING)
         self.status = RequestStatus.DECODING
+
+    def saw_tokens(self, t: float) -> None:
+        """A chunk boundary's read at ``t`` saw this row's tokens."""
+        if self.first_token_at is None:
+            self.first_token_at = t
 
     def record_trace(self, n_reasoning: int, n_evals: int, ema_var: float) -> None:
         if self.status is RequestStatus.DECODING:
@@ -125,7 +141,12 @@ class Request:
         if answer_tokens is not None:
             self.result["answer_tokens"] = answer_tokens
         if self.submitted_at is not None:
-            self.result["latency_s"] = time.perf_counter() - self.submitted_at
+            t0 = self.submitted_at
+            self.result["latency_s"] = time.perf_counter() - t0
+            for key, t in (("queue_s", self.admitted_at),
+                           ("first_token_s", self.first_token_at)):
+                if t is not None:
+                    self.result[key] = t - t0
         self.slot = None
 
     # ----------------------------------------------------------- queries

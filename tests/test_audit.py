@@ -184,6 +184,35 @@ def test_lowered_quick_matrix_clean():
     assert {"chunk", "shadow", "serve_step"} <= set(r.stats["families"])
 
 
+def test_lowered_program_names_are_distinct_per_family():
+    """Each program family lowers to a module of its own name
+    (``jit_<name>``), never ``jit_fn``, over the {self, proxy} x {ring,
+    paged} matrix — so a device trace can tell the programs apart."""
+    cells = [(t, k, "gather") for t in lowered.TIERS for k in lowered.KINDS]
+    r = lowered.run(cells=cells)
+    assert r.ok, "\n".join(str(v) for v in r.violations)
+    assert r.stats["modules"] == {
+        "admit": ["jit_admit"],
+        "admit_paged": ["jit_admit_paged"],
+        "chunk": ["jit_decode_chunk"],
+        "chunk_snapshot": ["jit_decode_chunk_snapshot"],
+        "decode": ["jit_decode_step"],
+        "pack": ["jit_pack_paged"],
+        "prefill": ["jit_prefill"],
+        "probe": ["jit_probe"],
+        "retract": ["jit_retract"],
+        "retract_lagged": ["jit_retract_lagged"],
+        "rollout": ["jit_rollout"],
+        "serve_step": ["jit_serve_step"],
+        "shadow": ["jit_observe_chunk"],
+        "stream_consume": ["jit_stream_consume"],
+        "stream_prefill": ["jit_stream_prefill"],
+        "stream_probe": ["jit_stream_probe"],
+    }
+    names = [n for ms in r.stats["modules"].values() for n in ms]
+    assert len(names) == len(set(names))
+
+
 # ------------------------------------------------------------------ the runner
 def test_runner_cli_static_passes(tmp_path):
     out = tmp_path / "report.json"

@@ -22,6 +22,7 @@ paper §4.2 / Fig. 5 through the serving stack):
 * CLI smoke — ``python -m repro.launch.serve --monitor proxy --requests 4``
   stays runnable (the tier-1 guard for the launcher path).
 """
+import json
 import os
 import subprocess
 import sys
@@ -197,9 +198,9 @@ def test_proxy_exit_frees_pages_for_same_batch_admissions(gen_model):
     assert all(r["exit_reason"] == "eat" for r in out)
     # no-reuse lower bound: 14 lifetimes need >= 14 * (prompt + decode)
     # pages; 13 data pages only work because exits freed pages mid-batch
-    ptier = eng._ptier
-    assert ptier.alloc.pages_reused > 0
-    assert ptier.alloc.peak_pages_in_use <= 13
+    counters = out.telemetry.summary()["counters"]
+    assert counters["proxy.pages_reused"] > 0
+    assert counters["proxy.peak_pages"] <= 13
 
 
 def test_undersized_proxy_pool_still_serves_queue(gen_model):
@@ -217,7 +218,7 @@ def test_undersized_proxy_pool_still_serves_queue(gen_model):
     out = eng.serve(b["prompts"], b["prompt_len"], jax.random.PRNGKey(0),
                     batch_size=4, max_tokens=24)
     assert len(out) == 14 and all(r["exit_reason"] == "eat" for r in out)
-    assert eng._ptier.alloc.pages_reused > 0
+    assert out.telemetry.summary()["counters"]["proxy.pages_reused"] > 0
 
 
 def test_proxy_pool_too_small_for_one_request_fails_fast(gen_model):
@@ -292,3 +293,7 @@ def test_serve_cli_proxy_smoke():
     assert r.returncode == 0, r.stdout + r.stderr
     assert "served 4 requests" in r.stdout, r.stdout
     assert "monitor=proxy" in r.stdout, r.stdout
+    # the last line is the serve call's telemetry summary
+    summary = json.loads(r.stdout.strip().splitlines()[-1])
+    assert summary["spans"]["serve"]["count"] == 1
+    assert summary["counters"]["admissions"] == 2
