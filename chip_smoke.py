@@ -121,8 +121,9 @@ def phase_kernels(archs=(GEN, PROXY), *, interpret: bool = False,
 
         Hq, Hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.resolved_head_dim
         n_pages = batch * ranks + 1                   # + the trash page 0
-        kp = jax.random.normal(k3, (n_pages, page_size, Hkv, hd), dt)
-        vp = jax.random.normal(k4, (n_pages, page_size, Hkv, hd), dt)
+        # a two-layer lane-dense stack, read at its second layer
+        kp = jax.random.normal(k3, (2, n_pages, page_size, Hkv * hd), dt)
+        vp = jax.random.normal(k4, (2, n_pages, page_size, Hkv * hd), dt)
         counts = rng.integers(ranks // 4, ranks + 1, batch).astype(np.int32)
         perm = rng.permutation(np.arange(1, n_pages)).astype(np.int32)
         pages = np.zeros((batch, ranks), np.int32)
@@ -142,10 +143,10 @@ def phase_kernels(archs=(GEN, PROXY), *, interpret: bool = False,
                     jnp.asarray(bpos), jnp.asarray(q_pos))
             compare(f"{arch} paged_attention m={m}",
                     lambda q, kp, vp, pg, ct, bp, qp: paged_attention_pallas(
-                        q, kp, vp, pg, ct, bp, qp, scale=hd ** -0.5,
+                        q, kp, vp, pg, ct, bp, qp, 1, scale=hd ** -0.5,
                         interpret=interpret),
                     lambda q, kp, vp, pg, ct, bp, qp: paged_attention_xla(
-                        q, kp, vp, pg, bp, qp, scale=hd ** -0.5),
+                        q, kp, vp, pg, bp, qp, 1, scale=hd ** -0.5),
                     args, ATTENTION_TOL)
     return errs
 

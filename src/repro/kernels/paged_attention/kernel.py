@@ -14,10 +14,12 @@ for them.
 Layout (Mosaic tiles the last two dimensions of every block; each is
 either 8x128-aligned or the full array dimension):
 
-* one grid step moves one whole page for all kv heads: the pools are
-  viewed as (P, ps, Hkv*D) — a free reshape — and a block is
-  (1, ps, Hkv*D), full in both tiled dims; head ``h`` is the static lane
-  slice [h*D, (h+1)*D) of it;
+* the pools are the serving cache's layer stacks, lane-dense:
+  (L, P, ps, Hkv*D).  The layer index rides as a third scalar-prefetch
+  operand, so one grid step moves one whole page of one layer for all kv
+  heads straight out of the stack — block (1, 1, ps, Hkv*D), full in both
+  tiled dims; head ``h`` is the static lane slice [h*D, (h+1)*D) of it —
+  and no layer slice or relayout of a pool precedes the call;
 * q is regrouped to (B, Hkv, R, Dk) with R = m*g rounded up to a multiple
   of 8; padding rows carry position -1, so they are fully masked and are
   sliced off the output;
@@ -45,9 +47,9 @@ from jax.experimental.pallas import tpu as pltpu
 _NEG_INF = -1e30
 
 
-def _kernel(pages_ref, counts_ref, qp_ref, bpos_ref, q_ref, k_ref, v_ref,
-            o_ref, m_scr, l_scr, acc_scr, *, scale, window, n_ranks, n_kv,
-            dk, dv):
+def _kernel(pages_ref, counts_ref, layer_ref, qp_ref, bpos_ref, q_ref,
+            k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *, scale, window,
+            n_ranks, n_kv, dk, dv):
     b = pl.program_id(0)
     j = pl.program_id(1)
 
@@ -66,8 +68,8 @@ def _kernel(pages_ref, counts_ref, qp_ref, bpos_ref, q_ref, k_ref, v_ref,
         valid = (kp >= 0) & (kp <= qp)                # (R, ps)
         if window:
             valid &= (qp - kp) < window
-        k_all = k_ref[0].astype(jnp.float32)          # (ps, Hkv*Dk)
-        v_all = v_ref[0].astype(jnp.float32)          # (ps, Hkv*Dv)
+        k_all = k_ref[0, 0].astype(jnp.float32)       # (ps, Hkv*Dk)
+        v_all = v_ref[0, 0].astype(jnp.float32)       # (ps, Hkv*Dv)
         for h in range(n_kv):
             q = q_ref[0, h].astype(jnp.float32) * scale       # (R, Dk)
             k = k_all[:, h * dk:(h + 1) * dk]                 # (ps, Dk)
@@ -102,20 +104,22 @@ def _page_of(b, j, pg, ct):
 
 def paged_attention_pallas(
     q: jax.Array,        # (B, m, Hq, Dk)   m small (decode/probe positions)
-    k_pool: jax.Array,   # (P, ps, Hkv, Dk) physical page pool
-    v_pool: jax.Array,   # (P, ps, Hkv, Dv)
+    k_pool: jax.Array,   # (L, P, ps, Hkv*Dk) stacked physical page pools
+    v_pool: jax.Array,   # (L, P, ps, Hkv*Dv)
     pages: jax.Array,    # (B, NBK) int32 physical page per mapped rank
     counts: jax.Array,   # (B,) int32 mapped ranks per row
     bpos: jax.Array,     # (B, NBK, ps) int32 positions (-1 = masked)
     q_pos: jax.Array,    # (B, m)
+    layer=0,             # int32 scalar: the layer of the stacks to read
     *,
     window: int = 0,
     scale: float | None = None,
     interpret: bool = False,
 ) -> jax.Array:
     B, m, Hq, Dk = q.shape
-    P_, ps, Hkv = k_pool.shape[0], k_pool.shape[1], k_pool.shape[2]
-    Dv = v_pool.shape[-1]
+    ps = k_pool.shape[2]
+    Hkv = k_pool.shape[3] // Dk
+    Dv = v_pool.shape[3] // Hkv
     NBK = pages.shape[1]
     g = Hq // Hkv
     rows = m * g
@@ -131,24 +135,25 @@ def paged_attention_pallas(
         qpg = jnp.pad(qpg, ((0, 0), (0, R - rows)), constant_values=-1)
     qpg = qpg.astype(jnp.int32)[:, :, None]                  # (B, R, 1)
     bpos = bpos.astype(jnp.int32).reshape(B * NBK, 1, ps)
-    k_flat = k_pool.reshape(P_, ps, Hkv * Dk)
-    v_flat = v_pool.reshape(P_, ps, Hkv * Dv)
+
+    def kv_block(b, j, pg, ct, ly):
+        # the page-table hop: rank j of row b -> physical page of layer ly
+        return (ly[0], _page_of(b, j, pg, ct), 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,               # pages, counts
+        num_scalar_prefetch=3,               # pages, counts, layer
         grid=(B, NBK),
         in_specs=[
-            pl.BlockSpec((1, R, 1), lambda b, j, pg, ct: (b, 0, 0)),
-            pl.BlockSpec((1, 1, ps), lambda b, j, pg, ct: (b * NBK + j, 0, 0)),
-            pl.BlockSpec((1, Hkv, R, Dk), lambda b, j, pg, ct: (b, 0, 0, 0)),
-            # the page-table hop: rank j of row b -> physical pool page
-            pl.BlockSpec((1, ps, Hkv * Dk),
-                         lambda b, j, pg, ct: (_page_of(b, j, pg, ct), 0, 0)),
-            pl.BlockSpec((1, ps, Hkv * Dv),
-                         lambda b, j, pg, ct: (_page_of(b, j, pg, ct), 0, 0)),
+            pl.BlockSpec((1, R, 1), lambda b, j, pg, ct, ly: (b, 0, 0)),
+            pl.BlockSpec((1, 1, ps),
+                         lambda b, j, pg, ct, ly: (b * NBK + j, 0, 0)),
+            pl.BlockSpec((1, Hkv, R, Dk),
+                         lambda b, j, pg, ct, ly: (b, 0, 0, 0)),
+            pl.BlockSpec((1, 1, ps, Hkv * Dk), kv_block),
+            pl.BlockSpec((1, 1, ps, Hkv * Dv), kv_block),
         ],
         out_specs=pl.BlockSpec((1, Hkv, R, Dv),
-                               lambda b, j, pg, ct: (b, 0, 0, 0)),
+                               lambda b, j, pg, ct, ly: (b, 0, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((Hkv, R, 1), jnp.float32),
             pltpu.VMEM((Hkv, R, 1), jnp.float32),
@@ -163,7 +168,7 @@ def paged_attention_pallas(
         name="paged_attention",
         interpret=interpret,
     )(jnp.asarray(pages, jnp.int32), jnp.asarray(counts, jnp.int32),
-      qpg, bpos, qg, k_flat, v_flat)
+      jnp.asarray(layer, jnp.int32).reshape(1), qpg, bpos, qg, k_pool, v_pool)
     # back to (B, m, Hq, Dv)
     return out[:, :, :rows].reshape(B, Hkv, m, g, Dv).transpose(
         0, 2, 1, 3, 4).reshape(B, m, Hq, Dv)

@@ -5,7 +5,8 @@ sequential per-page online softmax):
 
 * ``paged_decode_attention`` — decode/probe attention straight off the
   physical page pools through a compacted per-row page list (no gathered
-  logical view; O(mapped pages) per token).
+  logical view; O(mapped pages) per token).  The pools are the cache's
+  lane-dense layer stacks (L, P, ps, Hkv*D), read at ``layer``.
 * ``ring_decode_attention``  — the SAME algorithm over a dense ring cache,
   viewed as logical blocks via a free reshape (all blocks "mapped").
 
@@ -53,12 +54,13 @@ def block_positions(kv_pos: jax.Array, pages: jax.Array,
 
 def paged_decode_attention(
     q: jax.Array,        # (B, m, Hq, Dk)
-    k_pool: jax.Array,   # (P, ps, Hkv, Dk)
-    v_pool: jax.Array,   # (P, ps, Hkv, Dv)
+    k_pool: jax.Array,   # (L, P, ps, Hkv*Dk) stacked lane-dense pools
+    v_pool: jax.Array,   # (L, P, ps, Hkv*Dv)
     pages: jax.Array,    # (B, NBK) int32
     counts: jax.Array,   # (B,) int32 mapped ranks per row
     bpos: jax.Array,     # (B, NBK, ps) int32 (-1 = masked)
     q_pos: jax.Array,    # (B, m)
+    layer=0,             # int32 scalar: the layer of the stacks to read
     *,
     window: int = 0,
     scale: float | None = None,
@@ -72,10 +74,10 @@ def paged_decode_attention(
         from repro.kernels.paged_attention.kernel import paged_attention_pallas
 
         return paged_attention_pallas(
-            q, k_pool, v_pool, pages, counts, bpos, q_pos,
+            q, k_pool, v_pool, pages, counts, bpos, q_pos, layer,
             window=window, scale=scale, interpret=interpret,
         )
-    return paged_attention_xla(q, k_pool, v_pool, pages, bpos, q_pos,
+    return paged_attention_xla(q, k_pool, v_pool, pages, bpos, q_pos, layer,
                                scale=scale, window=window)
 
 
@@ -112,10 +114,11 @@ def ring_decode_attention(
     if impl == "pallas":
         from repro.kernels.paged_attention.kernel import paged_attention_pallas
 
-        # the dense rows become a (B*NB)-page pool with an identity list
-        Hkv, Dk = k.shape[2], k.shape[3]
-        pool_k = k.reshape(B * NB, page_size, Hkv, Dk)
-        pool_v = v.reshape(B * NB, page_size, Hkv, v.shape[-1])
+        # the dense rows become a one-layer stack of (B*NB) lane-dense
+        # pages with an identity list
+        Hkv = k.shape[2]
+        pool_k = k.reshape(1, B * NB, page_size, Hkv * k.shape[3])
+        pool_v = v.reshape(1, B * NB, page_size, Hkv * v.shape[3])
         ranks = jnp.arange(NB, dtype=jnp.int32)[None, :]
         pages = jnp.arange(B, dtype=jnp.int32)[:, None] * NB + ranks
         counts = jnp.full((B,), NB, jnp.int32)

@@ -95,22 +95,27 @@ def block_decode_attention(
 
 def paged_attention_xla(
     q: jax.Array,        # (B, m, Hq, Dk)
-    k_pool: jax.Array,   # (P, ps, Hkv, Dk)  physical page pool
-    v_pool: jax.Array,   # (P, ps, Hkv, Dv)
+    k_pool: jax.Array,   # (L, P, ps, Hkv*Dk)  stacked physical page pools
+    v_pool: jax.Array,   # (L, P, ps, Hkv*Dv)
     pages: jax.Array,    # (B, NBK) int32 physical page per mapped-block rank
     bpos: jax.Array,     # (B, NBK, ps) int32 positions (-1 = masked)
     q_pos: jax.Array,    # (B, m)
+    layer=0,             # int32 scalar: the layer of the stacks to read
     *,
     scale: float,
     window: int = 0,
 ) -> jax.Array:
     """Block-bucketed gather of only-mapped pages, then the block scan.
 
-    The gather touches ``NBK`` pages per row — the compacted mapped-block
-    list, NOT the logical extent — so HBM traffic and compute are
-    O(mapped pages).  Padding ranks point at the trash page with all
-    positions -1: identity steps (see module docstring)."""
-    kb = k_pool[pages]                         # (B, NBK, ps, Hkv, Dk)
-    vb = v_pool[pages]
+    The gather touches ``NBK`` pages per row of one layer — the compacted
+    mapped-block list, NOT the logical extent, and never a whole layer of
+    the stack — so HBM traffic and compute are O(mapped pages).  Padding
+    ranks point at the trash page with all positions -1: identity steps
+    (see module docstring)."""
+    B, NBK = pages.shape
+    ps, Dk = k_pool.shape[2], q.shape[-1]
+    Hkv = k_pool.shape[3] // Dk
+    kb = k_pool[layer, pages].reshape(B, NBK, ps, Hkv, Dk)
+    vb = v_pool[layer, pages].reshape(B, NBK, ps, Hkv, -1)
     return block_decode_attention(q, kb, vb, bpos, q_pos,
                                   scale=scale, window=window)

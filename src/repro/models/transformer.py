@@ -56,29 +56,41 @@ def write_slots(cur, m: int, capacity: int):
 # every read from it is position-masked (pos=-1 slots contribute exactly
 # 0.0 in all attention impls), so the gathered logical view is
 # element-for-element identical to the ring buffer wherever it matters.
+#
+# GQA K/V pools are whole layer stacks (L, num_pages, page_size, Hkv*hd),
+# lane-dense as the paged-attention kernel reads them; ``layer`` picks the
+# layer, so a decode step writes one slot per row into the stack in place
+# and never slices a layer out of it.
 
 
-def gather_pages(pool: jax.Array, table: jax.Array) -> jax.Array:
+def gather_pages(pool: jax.Array, table: jax.Array,
+                 layer=None) -> jax.Array:
     """Physical pages -> the logical ring view.
 
-    pool: (P, ps, ...tail); table: (B, NB) int32.
+    pool: (P, ps, ...tail), or with ``layer`` a stack (L, P, ps, ...tail)
+    read at that layer; table: (B, NB) int32.
     Returns (B, NB*ps, ...tail) — the per-row logical cache the attention
     mask addresses by ``kv_pos`` exactly as it addresses the ring buffer.
     """
     B, NB = table.shape
-    g = pool[table]                                   # (B, NB, ps, ...tail)
-    return g.reshape((B, NB * pool.shape[1]) + pool.shape[2:])
+    g = pool[table] if layer is None else pool[layer, table]
+    return g.reshape((B, NB * g.shape[2]) + g.shape[3:])
 
 
 def scatter_pages(pool: jax.Array, table: jax.Array, slots: jax.Array,
-                  new: jax.Array) -> jax.Array:
-    """Write ``new`` (B, m, ...tail) at logical ``slots`` (m,) through the
-    page table.  Rows whose block is unmapped (table entry 0) land in the
-    trash page — a don't-care, since their ``pos`` stays -1/masked."""
-    ps = pool.shape[1]
+                  new: jax.Array, layer=None) -> jax.Array:
+    """Write ``new`` (B, m, ...) at logical ``slots`` (m,) through the page
+    table, reshaped to the pool's tail (heads fold into the lane-dense
+    GQA layout); with ``layer`` the pool is a stack written at that layer.
+    Rows whose block is unmapped (table entry 0) land in the trash page —
+    a don't-care, since their ``pos`` stays -1/masked."""
+    lead = () if layer is None else (layer,)
+    ps = pool.shape[len(lead) + 1]
     pages = table[:, slots // ps]                     # (B, m)
     offs = jnp.broadcast_to((slots % ps)[None, :], pages.shape)
-    return pool.at[pages, offs].set(new.astype(pool.dtype))
+    tail = pool.shape[len(lead) + 2:]
+    return pool.at[lead + (pages, offs)].set(
+        new.reshape(pages.shape + tail).astype(pool.dtype))
 
 
 def page_native_ok(cfg: ModelConfig, ctx: ShardCtx, m: int) -> bool:
@@ -247,15 +259,18 @@ def attn_block_cached(
     use_moe: bool, window: int = 0, attn_impl: str = "auto",
     cross_cache: tuple | None = None, enc_pos=None, x_extra=None,
     paged: tuple | None = None, paged_impl: str = "gather",
-    page_block: int = 16, interpret: bool = False,
+    page_block: int = 16, interpret: bool = False, layer=None,
 ):
     """Cached block (prefill m=S / decode m small).  Returns (x, entry, aux).
 
     ``entry`` holds this layer's cache arrays; new K/V are scattered into
     ``slots`` (B-shared (m,) int32) before the attention read.  With
     ``paged=(page_table, page_size, blocks)`` the entry arrays are page
-    POOLS ((P, ps, ...) instead of (B, C, ...)): new K/V scatter through
-    the page table, and the attention read depends on ``paged_impl``:
+    POOLS instead of (B, C, ...) rows: MLA latents are this layer's pools
+    (P, ps, ...); GQA ``k``/``v`` are the whole lane-dense layer stacks
+    (L, P, ps, Hkv*hd), written and read at ``layer`` in place.  New K/V
+    scatter through the page table, and the attention read depends on
+    ``paged_impl``:
 
     * ``"gather"`` (default): materialize the gathered logical view — same
       mask, same ``kv_pos``, bit-identical to the ring (docs/architecture.md);
@@ -294,8 +309,8 @@ def attn_block_cached(
         o = None
         if paged is not None:
             table, ps, blocks = paged
-            entry["k"] = scatter_pages(entry["k"], table, slots, k_new)
-            entry["v"] = scatter_pages(entry["v"], table, slots, v_new)
+            entry["k"] = scatter_pages(entry["k"], table, slots, k_new, layer)
+            entry["v"] = scatter_pages(entry["v"], table, slots, v_new, layer)
             if native and blocks is None:
                 # a silent gather fallback here would split the per-impl
                 # paged==ring pairing (the ring side WOULD run the block
@@ -315,13 +330,17 @@ def attn_block_cached(
                     kv_pos, blocks["pages"], blocks["logical"], ps)
                 o = paged_ops.paged_decode_attention(
                     q, entry["k"], entry["v"], blocks["pages"],
-                    blocks["count"], bpos, pos1d, window=window,
+                    blocks["count"], bpos, pos1d, layer, window=window,
                     scale=att.attn_scale(cfg), impl=paged_impl,
                     interpret=interpret, n_devices=ctx.n_devices,
                 )
             else:
-                k_view = gather_pages(entry["k"], table)
-                v_view = gather_pages(entry["v"], table)
+                # the logical view, back from lanes to heads
+                B = x.shape[0]
+                k_view = gather_pages(entry["k"], table, layer).reshape(
+                    (B, -1) + k_new.shape[2:])
+                v_view = gather_pages(entry["v"], table, layer).reshape(
+                    (B, -1) + v_new.shape[2:])
         else:
             ps = page_block
             entry["k"] = entry["k"].at[:, slots].set(k_new.astype(entry["k"].dtype))
@@ -520,6 +539,22 @@ def encode(params: Params, frames, enc_pos, cfg: ModelConfig, ctx: ShardCtx, *,
     return rmsnorm(x, params["enc_norm"], cfg.norm_eps, cfg.rmsnorm_one_plus)
 
 
+def _split_carried_pools(entry: dict, paged) -> tuple[dict, dict]:
+    """(carried, per-layer) parts of a segment's stacked cache entries.
+
+    A paged cache's GQA ``k``/``v`` pools ride the layer scan's CARRY with
+    the layer index: each layer scatters its new K/V into the stack at
+    (layer, page, offset) and reads its pages straight out of it, so XLA
+    updates the (donated) stack in place.  As scan ``xs``/``ys`` they would
+    be sliced out and stacked back, a whole layer pool each way, every
+    layer of every step.  Everything else — ring rows, MLA latent pools,
+    cross-attention K/V — stays per-layer in ``xs``/``ys``."""
+    if paged is None:
+        return {}, entry
+    carried = {n: a for n, a in entry.items() if n in ("k", "v")}
+    return carried, {n: a for n, a in entry.items() if n not in carried}
+
+
 def forward_cached(
     params: Params, x, positions, pos1d, slots, cache: Cache,
     cfg: ModelConfig, ctx: ShardCtx, *,
@@ -562,26 +597,30 @@ def forward_cached(
 
         new_layers = dict(layers)
         for seg_name, seg_params, use_moe in segs:
-            seg_cache = layers[seg_name]
+            pools, seg_cache = _split_carried_pools(layers[seg_name], paged)
             cross = cfg.arch_type == "encdec"
 
             def body(carry, xs):
-                xx, aux = carry
+                xx, aux, pools_l, li = carry
                 p_layer, entry = xs
                 cc = (entry["ck"], entry["cv"]) if cross else None
                 xx, entry_new, a = attn_block_cached(
-                    p_layer, xx, positions, pos1d, cfg, ctx, entry, kv_pos, slots,
+                    p_layer, xx, positions, pos1d, cfg, ctx,
+                    {**entry, **pools_l}, kv_pos, slots,
                     use_moe=use_moe, window=window, attn_impl=attn_impl,
                     cross_cache=cc, enc_pos=cache.get("enc_pos"), paged=paged,
                     paged_impl=paged_impl, page_block=page_block,
-                    interpret=interpret,
+                    interpret=interpret, layer=li,
                 )
+                pools_l = {n: entry_new.pop(n) for n in pools_l}
                 if cross:  # cross kv is static; don't re-emit to save copies
                     entry_new["ck"], entry_new["cv"] = entry["ck"], entry["cv"]
-                return (xx, aux + a), entry_new
+                return (xx, aux + a, pools_l, li + 1), entry_new
 
-            (x, aux_total), seg_new = _scan(body, (x, aux_total), (seg_params, seg_cache), remat=False, unroll=unroll)
-            new_layers[seg_name] = seg_new
+            (x, aux_total, pools, _), seg_new = _scan(
+                body, (x, aux_total, pools, jnp.zeros((), jnp.int32)),
+                (seg_params, seg_cache), remat=False, unroll=unroll)
+            new_layers[seg_name] = {**seg_new, **pools}
         new_cache["layers"] = new_layers
 
     elif cfg.arch_type == "ssm":
@@ -603,12 +642,13 @@ def forward_cached(
     elif cfg.arch_type == "hybrid":
         emb0 = x
         seg_cache = layers["ssm_seg"]      # pytree stacked (G, n_ssm_per, ...)
-        attn_cache = layers["attn_seg"]    # entries stacked (G, ...)
+        # entries stacked (G, ...)
+        pools, attn_cache = _split_carried_pools(layers["attn_seg"], paged)
         use_full = m > 16
         valid = pos1d >= 0
 
         def body_g(carry, xs):
-            xx, aux = carry
+            xx, aux, pools_g, gi = carry
             p_group, st_group, attn_entry = xs
 
             def body_s(xxx, xs_inner):
@@ -622,18 +662,21 @@ def forward_cached(
             xx, st_group_new = _scan(body_s, xx, (p_group, st_group), remat=False, unroll=unroll)
             xx, attn_entry_new, a = attn_block_cached(
                 params["shared_attn"], xx, positions, pos1d, cfg, ctx,
-                attn_entry, kv_pos, slots, use_moe=False, window=window,
-                attn_impl=attn_impl, x_extra=emb0, paged=paged,
+                {**attn_entry, **pools_g}, kv_pos, slots, use_moe=False,
+                window=window, attn_impl=attn_impl, x_extra=emb0, paged=paged,
                 paged_impl=paged_impl, page_block=page_block,
-                interpret=interpret,
+                interpret=interpret, layer=gi,
             )
-            return (xx, aux + a), (st_group_new, attn_entry_new)
+            pools_g = {n: attn_entry_new.pop(n) for n in pools_g}
+            return (xx, aux + a, pools_g, gi + 1), (st_group_new, attn_entry_new)
 
-        (x, aux_total), (st_new, attn_new) = _scan(
-            body_g, (x, aux_total), (params["groups"], seg_cache, attn_cache),
+        (x, aux_total, pools, _), (st_new, attn_new) = _scan(
+            body_g, (x, aux_total, pools, jnp.zeros((), jnp.int32)),
+            (params["groups"], seg_cache, attn_cache),
             remat=False, unroll=unroll,
         )
-        new_cache["layers"] = {"ssm_seg": st_new, "attn_seg": attn_new}
+        new_cache["layers"] = {"ssm_seg": st_new,
+                               "attn_seg": {**attn_new, **pools}}
 
     else:
         raise ValueError(cfg.arch_type)

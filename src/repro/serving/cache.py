@@ -23,9 +23,12 @@ explicit ``pos`` array, so ring order is irrelevant to attention.
 
 Block-paged variant (``CacheConfig.kind="paged"``, docs/architecture.md):
 same logical layout and ``pos``/``cur`` semantics, but the attention
-entries become page POOLS — ``(L, num_pages, page_size, Hkv, hd)`` instead
-of ``(L, B, C, Hkv, hd)`` — plus a ``page_table`` (B, NB) int32 mapping
-each row's logical blocks to physical pages.  ``gather_pages`` reconstructs
+entries become page POOLS — ``(L, num_pages, page_size, Hkv*hd)`` instead
+of ``(L, B, C, Hkv, hd)``: lane-dense, the layout the paged-attention
+kernel reads, and written in place at (layer, page, offset) by the layer
+scan (MLA latents: ``(L, num_pages, page_size, r)``) — plus a
+``page_table`` (B, NB) int32 mapping each row's logical blocks to physical
+pages.  ``gather_pages`` reconstructs
 the per-row logical view for attention; unmapped blocks read the reserved
 trash page (entry 0), whose contents are always position-masked.  The
 physical footprint is live tokens (page-granular), not batch-lifetime
@@ -187,7 +190,8 @@ def alloc_cache(cfg: ModelConfig, batch: int, capacity: int, dtype=None) -> dict
 
 def _pooled_attn_entry(cfg: ModelConfig, lead: tuple[int, ...],
                        num_pages: int, page_size: int, dtype):
-    """Page-pool form of ``_attn_entry``: (B, C) -> (num_pages, page_size)."""
+    """Page-pool form of ``_attn_entry``: (B, C) -> (num_pages, page_size),
+    GQA heads folded into lanes (Hkv, hd) -> Hkv*hd."""
     hd = cfg.resolved_head_dim
     if cfg.mla is not None:
         m = cfg.mla
@@ -196,8 +200,8 @@ def _pooled_attn_entry(cfg: ModelConfig, lead: tuple[int, ...],
             "kr": jnp.zeros(lead + (num_pages, page_size, m.qk_rope_head_dim), dtype),
         }
     return {
-        "k": jnp.zeros(lead + (num_pages, page_size, cfg.n_kv_heads, hd), dtype),
-        "v": jnp.zeros(lead + (num_pages, page_size, cfg.n_kv_heads, hd), dtype),
+        "k": jnp.zeros(lead + (num_pages, page_size, cfg.n_kv_heads * hd), dtype),
+        "v": jnp.zeros(lead + (num_pages, page_size, cfg.n_kv_heads * hd), dtype),
     }
 
 
@@ -302,9 +306,9 @@ def alloc_paged_cache(cfg: ModelConfig, batch: int, capacity: int,
     return cache
 
 
-# pool rank of a single-layer pooled entry (page, page_size, ...tail); any
-# extra leading axes are layer stacks
-_POOL_NDIM = {"k": 4, "v": 4, "c": 3, "kr": 3}
+# rank of a single-layer pooled entry (page, page_size, lanes); any extra
+# leading axes are layer stacks
+_POOL_NDIM = 3
 
 
 def _is_pooled(path: str) -> bool:
@@ -344,9 +348,9 @@ def pack_paged_cache(paged: dict, dense: dict, table) -> dict:
             merged.append(dense["cur"])
         elif _is_pooled(path):
             src = flat_d[path]
-            lead = leaf.ndim - _POOL_NDIM[name]
+            lead = leaf.ndim - _POOL_NDIM
             B = src.shape[lead]
-            tail = src.shape[lead + 2:]
+            tail = leaf.shape[lead + 2:]        # heads fold into lanes
             srcb = src.reshape(src.shape[:lead] + (B, nbp, ps) + tail)
             idx = (slice(None),) * lead + (table[:, :nbp],)
             merged.append(leaf.at[idx].set(srcb.astype(leaf.dtype)))
@@ -391,8 +395,8 @@ def merge_paged_row(cache: dict, one: dict, row, row_table) -> dict:
             merged.append(jnp.maximum(leaf, one["cur"]))
         elif _is_pooled(path):
             src = flat_one[path]
-            lead = leaf.ndim - _POOL_NDIM[name]
-            tail = src.shape[lead + 2:]
+            lead = leaf.ndim - _POOL_NDIM
+            tail = leaf.shape[lead + 2:]        # heads fold into lanes
             srcb = src[(slice(None),) * lead + (0,)]
             srcb = srcb.reshape(src.shape[:lead] + (nbp, ps) + tail)
             idx = (slice(None),) * lead + (jnp.asarray(row_table)[:nbp],)
@@ -497,12 +501,11 @@ def cache_pspecs(cfg: ModelConfig, ctx: ShardCtx, cache) -> dict:
     b = ctx.batch_entry_for(bsz)
 
     def pool_spec_for(path_leaf: str, lead: int) -> P:
-        # pooled entries: (lead..., num_pages, page_size, ...tail)
-        if path_leaf in ("k", "v"):
-            if kv_on_model:
-                return P(*([None] * lead), None, None, m, None)
-            return P(*([None] * lead), None, m, None, None)  # shard page_size
-        return P(*([None] * lead), None, m, None)            # c/kr
+        # pooled entries: (lead..., num_pages, page_size, lanes); GQA lanes
+        # are Hkv*hd, head-major, so sharding them shards whole kv heads
+        if path_leaf in ("k", "v") and kv_on_model:
+            return P(*([None] * lead), None, None, m)
+        return P(*([None] * lead), None, m, None)            # shard page_size
     def spec_for(path_leaf: str, ndim: int, lead: int) -> P:
         # lead = number of stacked layer axes before the batch axis
         if path_leaf == "page_table":
@@ -539,7 +542,7 @@ def cache_pspecs(cfg: ModelConfig, ctx: ShardCtx, cache) -> dict:
             continue
         # count stacked lead axes: layers/<seg>/... entries have ndim-known
         if paged and _is_pooled(path):
-            specs.append(pool_spec_for(leafname, leaf.ndim - _POOL_NDIM[leafname]))
+            specs.append(pool_spec_for(leafname, leaf.ndim - _POOL_NDIM))
             continue
         lead = 0
         if parts[0] == "layers":

@@ -85,7 +85,9 @@ def make_paged_case(mapped, *, Hq=4, Hkv=2, Dk=16, Dv=16, ps=16, NB=16,
         q=q, q_pos=q_pos,
         kd=jnp.asarray(kd, dtype), vd=jnp.asarray(vd, dtype),
         kv_pos=jnp.asarray(kv_pos),
-        kp=jnp.asarray(kp, dtype), vp=jnp.asarray(vp, dtype),
+        # the cache's pool layout: a (one-layer) lane-dense stack
+        kp=jnp.asarray(kp.reshape(1, P, ps, Hkv * Dk), dtype),
+        vp=jnp.asarray(vp.reshape(1, P, ps, Hkv * Dv), dtype),
         pages=jnp.asarray(pages), logical=jnp.asarray(logical),
         counts=jnp.asarray(counts), ps=ps,
     )
@@ -135,6 +137,32 @@ def test_paged_equals_ring_bitwise(case):
             c["q_pos"], scale=0.25, impl=impl, interpret=True)
         np.testing.assert_array_equal(np.asarray(ring), np.asarray(paged),
                                       err_msg=impl)
+
+
+@pytest.mark.parametrize("layer", [0, 2])
+@pytest.mark.parametrize("Hq,Hkv", [(16, 8), (12, 2)],
+                         ids=["qwen3-1.7b", "eat-proxy-1.5b"])
+def test_kernel_reads_its_layer_of_the_stack(Hq, Hkv, layer):
+    """The kernel reads layer ``layer`` of a three-layer lane-dense stack
+    straight through its index maps, and equals the XLA reference run on
+    that layer alone — at the served models' kv widths, with rows whose
+    trailing ranks are skipped."""
+    from repro.kernels.paged_attention.kernel import paged_attention_pallas
+    from repro.kernels.paged_attention.ref import paged_attention_xla
+
+    cases = [make_paged_case(HOLES, Hq=Hq, Hkv=Hkv, Dk=128, Dv=128, m=2,
+                             seed=s) for s in range(3)]
+    c = cases[layer]
+    assert (np.asarray(c["counts"]) < c["pages"].shape[1]).all()
+    kp = jnp.concatenate([x["kp"] for x in cases])
+    vp = jnp.concatenate([x["vp"] for x in cases])
+    out = paged_attention_pallas(
+        c["q"], kp, vp, c["pages"], c["counts"], c["bpos"], c["q_pos"],
+        jnp.int32(layer), scale=128 ** -0.5, interpret=True)
+    ref = paged_attention_xla(c["q"], c["kp"], c["vp"], c["pages"],
+                              c["bpos"], c["q_pos"], scale=128 ** -0.5)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               atol=2e-5, rtol=2e-5)
 
 
 def test_ring_op_pads_non_multiple_capacity():

@@ -257,9 +257,8 @@ def test_alloc_paged_cache_validation():
         alloc_paged_cache(cfg, 2, 256, 16, 1)
     cache = alloc_paged_cache(cfg, 2, 256, 16, 8)
     assert cache["page_table"].shape == (2, 16)
-    assert cache["layers"]["seg"]["k"].shape == (cfg.n_layers, 8, 16,
-                                                 cfg.n_kv_heads,
-                                                 cfg.resolved_head_dim)
+    assert cache["layers"]["seg"]["k"].shape == (
+        cfg.n_layers, 8, 16, cfg.n_kv_heads * cfg.resolved_head_dim)
     # and the ring allocator still produces the dense layout
     dense = alloc_cache(cfg, 2, 256)
     assert "page_table" not in dense
