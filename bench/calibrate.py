@@ -56,6 +56,7 @@ def main(argv=None) -> int:
 
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     dev = jax.devices()[0]
+    used = jax.devices()[:c["cell"]["chips"]]
     print(f"device: {dev.platform} {dev.device_kind} x{jax.device_count()}")
     t0 = time.perf_counter()
     engine = harness.build_engine(cfg, mix)
@@ -112,25 +113,21 @@ def main(argv=None) -> int:
         print(f"seed {seed}: job {dt:.3f} s, n "
               f"{[r['n_reasoning'] for r in res]}")
         served.append((seed, job, res))
-    out["memory_peak_bytes"] = int(
-        (dev.memory_stats() or {}).get("peak_bytes_in_use", 0))
+    out["memory_peak_bytes"] = max(
+        int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
+        for d in used)
     print(f"memory peak {out['memory_peak_bytes']} bytes")
     if args.control:
-        # the reference runs once the served model is freed, as in a run
+        # the reference runs once the served model is freed, over the
+        # cell's chips, as in a run
         del engine
         gc.collect()
-        from bench import reference as ref
-
-        weights = {"generator": ref.init_weights(
-            cfg["generator"]["model"], cfg["generator"]["weights_seed"])}
-        if cfg["monitor"] == "proxy":
-            weights["proxy"] = ref.init_weights(
-                cfg["proxy"]["model"], cfg["proxy"]["weights_seed"])
+        weights = cmp.reference_weights(c["reference"], cfg, used)
         for seed, job, res in served:
             finished = [(unpadded(job, i), r) for i, r in enumerate(res)]
             checked = [finished[i] for i in cmp.sample(res, seed)]
             t0 = time.perf_counter()
-            nums = cmp.compare(cfg, mix, checked, weights,
+            nums = cmp.compare(cfg, mix, checked, c["reference"], weights,
                                margin=c["limits"]["eat_var_rel"],
                                control=True)
             out["seeds"][seed]["readings"] = nums

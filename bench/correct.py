@@ -2,8 +2,12 @@
 
 Once the window has closed and the served model is freed, a sample of the
 requests the window finished (drawn from the seed, the longest always in
-it) is run through the plain reference (``bench/reference.py``), one
-forward per request over its prompt and the tokens the program served:
+it) is run through the plain reference, one forward per request over its
+prompt and the tokens the program served.  The configuration names its
+reference (``"reference": "<name>"``); ``bench/references/<name>.py``
+gives ``init_weights(model, seed, devices)``, the served weights drawn
+again from their seed over the cell's chips, and ``forward(model, w,
+tokens, pos, mask, rows, quantize=None)``, float32 logits at ``rows``.
 
 * ``answer_gap`` — the widest gap by which a greedy forced-answer token's
   reference logit lies below the reference's best logit at that position.
@@ -28,7 +32,6 @@ from __future__ import annotations
 
 import numpy as np
 
-from bench import reference as ref
 from bench.traffic import job_seed
 
 CHECK_REQUESTS = 8      # requests the reference checks per run
@@ -97,7 +100,21 @@ def sequence(prompt, reasoning, answer, *, every: int, probe: list,
             "n_eat": len(eat_rows), "n_ans": len(ans_rows)}
 
 
-def run_reference(m: dict, w, seqs: list[dict], quantize=None) -> list:
+def reference_weights(ref, cfg: dict, devices) -> dict:
+    """The reference's weights of every model a configuration serves."""
+    return {side: ref.init_weights(cfg[side]["model"],
+                                   cfg[side]["weights_seed"], devices)
+            for side in ("generator", "proxy") if cfg.get(side)}
+
+
+def entropy(logits: np.ndarray) -> np.ndarray:
+    """Shannon entropy (nats) over the last axis, in float64."""
+    z = logits - logits.max(-1, keepdims=True)
+    logp = z - np.log(np.exp(z).sum(-1, keepdims=True))
+    return -(np.exp(logp) * logp).sum(-1)
+
+
+def run_reference(ref, m: dict, w, seqs: list[dict], quantize=None) -> list:
     """Logits (rows, vocab) of each sequence's checked rows."""
     out = []
     for b in range(0, len(seqs), BATCH):
@@ -160,12 +177,12 @@ def sample(results: list[dict], seed: int, k: int = CHECK_REQUESTS) -> list:
     return [longest] + [int(i) for i in rest[:k - 1]]
 
 
-def compare(cfg: dict, mix: dict, finished: list[tuple], weights: dict, *,
-            margin: float, control: bool = False) -> dict:
+def compare(cfg: dict, mix: dict, finished: list[tuple], ref,
+            weights: dict, *, margin: float, control: bool = False) -> dict:
     """The three numbers for the program and, with ``control``, for the
     float8 reference in its place.  ``finished`` is a list of
-    ``(prompt tokens, result)``; ``weights`` maps "generator"/"proxy" to
-    reference weights."""
+    ``(prompt tokens, result)``; ``ref`` is the reference module and
+    ``weights`` its ``reference_weights``."""
     sh = shapes(mix)
     every, delta = mix["eval_every"], float(cfg["delta"])
     seqs = [sequence(p, r["reasoning_tokens"], r["answer_tokens"],
@@ -176,12 +193,12 @@ def compare(cfg: dict, mix: dict, finished: list[tuple], weights: dict, *,
 
     def logits(quantize):
         """(generator logits, monitor logits) of every sequence."""
-        gen = run_reference(cfg["generator"]["model"], weights["generator"],
-                            seqs, quantize)
+        gen = run_reference(ref, cfg["generator"]["model"],
+                            weights["generator"], seqs, quantize)
         if mon == "generator":
             return gen, gen
-        return gen, run_reference(cfg["proxy"]["model"], weights["proxy"],
-                                  seqs, quantize)
+        return gen, run_reference(ref, cfg["proxy"]["model"],
+                                  weights["proxy"], seqs, quantize)
 
     ref_gen, ref_mon = logits(None)
     sides = {"program": None}
@@ -200,13 +217,13 @@ def compare(cfg: dict, mix: dict, finished: list[tuple], weights: dict, *,
                 picked = low[0][i][ne:].argmax(-1)
             gap = max(gap, float(np.max(
                 ref_ans.max(-1) - ref_ans[np.arange(len(picked)), picked])))
-            ref_v = ema_vars(ref.entropy(ref_mon[i][:ne]), cfg["alpha"])
+            ref_v = ema_vars(entropy(ref_mon[i][:ne]), cfg["alpha"])
             ref_by_k = dict(enumerate(ref_v, 1))
             if low is None:
                 got_by_k = served_vars(r["eat_trace"])
                 got_exit = served_exit(r, every)
             else:
-                got_v = ema_vars(ref.entropy(low[1][i][:ne]), cfg["alpha"])
+                got_v = ema_vars(entropy(low[1][i][:ne]), cfg["alpha"])
                 got_by_k = dict(enumerate(got_v, 1))
                 got_exit = exit_eval(got_by_k, delta, cfg["min_evals"])
                 if got_exit is not None and got_exit < ne:

@@ -1,7 +1,7 @@
 """Reduction of a JAX profiler trace (``.xplane.pb``) to the numbers the
-per-layer metrics read: device busy time, kernel time by name, the device
-operations that took most time, and the longest idle gaps named by what
-the host was doing.
+per-layer metrics read: device busy time, kernel time by name, collective
+time, the device operations that took most time, and the longest idle
+gaps named by what the host was doing.
 
 The traced window is the host span named ``WINDOW_SPAN`` that the harness
 opens around one whole job.  Host spans the harness opens around each
@@ -20,6 +20,10 @@ OPS_LINE = "XLA Ops"
 #: gaps shorter than this lie between the operations of one program
 SHORT_GAP_NS = 10_000
 SHORT_GAP = "between device ops (<10 us)"
+#: op families of the exchanges between chips, as a TPU trace names them
+#: (``%all-reduce.3``, ``%all-gather-start.1``, ``%reduce-scatter.2``)
+COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter",
+               "collective-permute", "all-to-all")
 
 
 def _union(intervals):
@@ -88,10 +92,14 @@ def reduce_trace(pd, kernels=("paged_attention", "entropy_probe")) -> dict:
     ``window_s``   length of the traced job (the ``WINDOW_SPAN`` host span)
     ``busy_s``     union of device-op intervals inside it, mean over chips
     ``kernel_s``   {kernel: summed device duration}, ``kernel_calls`` counts
-                   (ops whose instruction name starts with the kernel's)
+                   (ops whose instruction name starts with the kernel's),
+                   mean over chips
+    ``collective_s`` summed device duration of the ops whose instruction
+                   name starts with one of ``COLLECTIVES``, mean over chips
     ``device_ops`` [[op family, seconds]] of the 10 largest leaf-op totals,
-                   summed over chips
-    ``idle_gaps``  [[host activity, seconds]] of the 10 largest idle totals
+                   mean over chips
+    ``idle_gaps``  [[host activity, seconds]] of the 10 largest idle totals,
+                   mean over chips
     ``n_devices``  device planes read
     """
     spans = list(host_spans(pd))
@@ -104,7 +112,7 @@ def reduce_trace(pd, kernels=("paged_attention", "entropy_probe")) -> dict:
     planes = device_planes(pd)
     if not planes:
         raise ValueError("trace has no device plane")
-    busy_total = 0.0
+    busy_total = collective_total = 0.0
     kernel_s = defaultdict(float)
     kernel_calls = defaultdict(int)
     ops = defaultdict(float)
@@ -117,6 +125,8 @@ def reduce_trace(pd, kernels=("paged_attention", "entropy_probe")) -> dict:
             d = min(e, t1) - max(s, t0)
             if leaf:
                 ops[_op_family(name)] += d
+            if name.startswith(COLLECTIVES):
+                collective_total += d
             for k in kernels:
                 if name.startswith(k):
                     kernel_s[k] += d
@@ -129,13 +139,14 @@ def reduce_trace(pd, kernels=("paged_attention", "entropy_probe")) -> dict:
                 gaps[owner] += s - prev
             prev = max(prev, e)
     n = len(planes)
-    top = lambda d: [[k, v * 1e-9] for k, v in  # noqa: E731
+    top = lambda d: [[k, v * 1e-9 / n] for k, v in  # noqa: E731
                      sorted(d.items(), key=lambda kv: -kv[1])[:10]]
     return {
         "window_s": (t1 - t0) * 1e-9,
         "busy_s": busy_total * 1e-9 / n,
         "kernel_s": {k: v * 1e-9 / n for k, v in kernel_s.items()},
         "kernel_calls": {k: v // n for k, v in kernel_calls.items()},
+        "collective_s": collective_total * 1e-9 / n,
         "device_ops": top(ops),
         "idle_gaps": top(gaps),
         "n_devices": n,

@@ -98,9 +98,10 @@ def entropy_probe_work(m: dict, rows: int, itemsize: int = BF16):
 
 
 def roofline_share(flops: float, nbytes: float, seconds: float,
-                   peaks: dict) -> tuple[float, str]:
-    """(least time / measured time, the bound that sets the least time)."""
-    t_flops = flops / peaks["bf16_flops"]
-    t_bytes = nbytes / peaks["hbm_bytes_per_s"]
+                   peaks: dict, chips: int = 1) -> tuple[float, str]:
+    """(least time / measured time, the bound that sets the least time),
+    the work spread over ``chips`` chips and ``seconds`` their mean time."""
+    t_flops = flops / (chips * peaks["bf16_flops"])
+    t_bytes = nbytes / (chips * peaks["hbm_bytes_per_s"])
     bound = "memory" if t_bytes >= t_flops else "compute"
     return max(t_flops, t_bytes) / seconds, bound

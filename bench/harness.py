@@ -16,20 +16,23 @@ import numpy as np
 from bench import traffic as tr
 from bench.devtrace import HOST_SPAN_PREFIX, WINDOW_SPAN
 
-#: ModelConfig attributes a configuration file's ``model`` block pins
-MODEL_KEYS = {"n_layers": "n_layers", "d_model": "d_model",
-              "n_heads": "n_heads", "n_kv_heads": "n_kv_heads",
-              "head_dim": "resolved_head_dim", "d_ff": "d_ff",
-              "vocab": "vocab", "qk_norm": "qk_norm",
-              "attn_bias": "attn_bias", "tie_embeddings": "tie_embeddings",
-              "rope_theta": "rope_theta", "norm_eps": "norm_eps",
-              "dtype": "dtype"}
+#: keys of a configuration file's ``model`` block that the registry holds
+#: under another attribute name; every other key is the attribute's own
+MODEL_ATTRS = {"head_dim": "resolved_head_dim"}
 
 
 # ------------------------------------------------------------------ engine
+def mesh_devices(cfg: dict) -> int:
+    """Devices of the configuration's ``"mesh": "<data>x<model>"``
+    (absent: one)."""
+    data, model = (int(x) for x in cfg.get("mesh", "1x1").split("x"))
+    return data * model
+
+
 def launcher_argv(cfg: dict, mix: dict) -> list[str]:
     """The ``repro.launch.serve`` command line of a cell's engine."""
-    argv = ["--arch", cfg["generator"]["arch"], "--mesh", "1x1",
+    argv = ["--arch", cfg["generator"]["arch"],
+            "--mesh", cfg.get("mesh", "1x1"),
             "--requests", str(mix["requests_per_job"]),
             "--batch", str(mix["slots"]), "--budget", str(mix["budget"]),
             "--chunk", str(mix["chunk"]),
@@ -45,12 +48,21 @@ def launcher_argv(cfg: dict, mix: dict) -> list[str]:
 
 
 def check_model(model_cfg, block: dict, what: str) -> None:
-    """The program's registry entry must be the configuration file's."""
-    for key, attr in MODEL_KEYS.items():
+    """The program's registry entry must be the configuration file's, key
+    by key: a key the registry does not hold is an error, and a nested
+    block (such as experts) is checked against the nested entry."""
+    for key, want in block.items():
+        attr = MODEL_ATTRS.get(key, key)
+        if not hasattr(model_cfg, attr):
+            raise ValueError(f"{what}: the registry holds no {attr!r} to "
+                             f"check the configuration file's {key}={want!r}"
+                             f" against")
         got = getattr(model_cfg, attr)
-        if got != block[key]:
+        if isinstance(want, dict):
+            check_model(got, want, f"{what}.{key}")
+        elif got != want:
             raise ValueError(f"{what}: registry {attr}={got!r} but the "
-                             f"configuration file says {key}={block[key]!r}")
+                             f"configuration file says {key}={want!r}")
 
 
 def build_engine(cfg: dict, mix: dict):

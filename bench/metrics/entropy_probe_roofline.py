@@ -2,8 +2,9 @@
 
 Every call must read the monitor model's unembedding once (d x padded
 vocab, bf16); every EAT evaluation a request consumed needs one row of
-dot products over it.  The least time (memory-bound at these shapes) over
-the summed device time of the ``entropy_probe`` events.  The monitor is
+dot products over it.  The least time on every chip the trace holds
+(memory-bound at these shapes) over the summed device time of the
+``entropy_probe`` events, mean over chips.  The monitor is
 the generator in a self-EAT cell and the proxy in a proxy cell."""
 from bench.flops import entropy_probe_work, roofline_share
 
@@ -19,5 +20,5 @@ def read(rec):
     flops = entropy_probe_work(m, evals)[0]
     nbytes = calls * entropy_probe_work(m, 1)[1]
     share, _ = roofline_share(flops, nbytes, t["kernel_s"]["entropy_probe"],
-                              rec["peaks"])
+                              rec["peaks"], t["n_devices"])
     return 100.0 * share

@@ -4,8 +4,9 @@ The least time is the larger of the attention FLOPs over the bf16 peak and
 the K/V bytes over the HBM bandwidth, for the calls the served requests
 needed (``bench/flops.py``): the generator's decode steps and forced
 answers, the probe on the monitor model, and in a proxy cell the proxy's
-shadow decode.  It is divided by the summed device time of every
-``paged_attention`` event.  The bound is memory at these shapes."""
+shadow decode.  The peaks are those of every chip the trace holds, and
+the time the summed device time of the ``paged_attention`` events, mean
+over chips.  The bound is memory at these shapes."""
 from bench.flops import paged_attention_work, roofline_share
 
 
@@ -27,5 +28,5 @@ def read(rec):
                                         every, a, answer=False)
             flops, nbytes = flops + f, nbytes + b
     share, _ = roofline_share(flops, nbytes, t["kernel_s"]["paged_attention"],
-                              rec["peaks"])
+                              rec["peaks"], t["n_devices"])
     return 100.0 * share

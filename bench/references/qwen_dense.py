@@ -20,8 +20,9 @@ prompt token.
 
 Everything runs in float32 under ``jax.default_matmul_precision("highest")``
 (a TPU otherwise multiplies float32 in bfloat16), one layer at a time
-inside a scan, and a few requests at a time, so it fits beside nothing
-else on the chip once the served model is freed.  ``quantize="fp8"`` is
+inside a scan, and a few requests at a time, with the weights split over
+the cell's chips (``init_weights``), so it fits beside nothing else on
+them once the served model is freed.  ``quantize="fp8"`` is
 the control, computed in the next precision below the served bfloat16:
 every weight matrix and every input of a matmul (the attention's queries,
 keys, values and weights among them) rounded to float8 e4m3 with one
@@ -35,6 +36,7 @@ import math
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from bench.flops import head_dim, padded_vocab
 
@@ -82,12 +84,39 @@ def _init(m: dict, key, dtype):
     return w
 
 
-def init_weights(m: dict, seed: int):
+#: the axis of each weight split over the devices: the output columns of
+#: the projections that fan out, the input rows of the two that fan back
+#: in, the vocabulary rows of the embedding; every other leaf (the norms)
+#: and any axis the device count does not divide is replicated
+SPLIT = {"embedding": 0, "lm_head": -1, "wq": -1, "wk": -1, "wv": -1,
+         "bq": -1, "bk": -1, "bv": -1, "w_gate": -1, "w_up": -1,
+         "wo": -2, "w_down": -2}
+
+
+def _placement(shapes, devices):
+    mesh = Mesh(np.asarray(devices), ("x",))
+
+    def sharding(path, leaf):
+        axis = SPLIT.get(path[-1].key)
+        spec = [None] * leaf.ndim
+        if axis is not None and leaf.shape[axis] % len(devices) == 0:
+            spec[axis] = "x"
+        return NamedSharding(mesh, PartitionSpec(*spec))
+
+    return jax.tree_util.tree_map_with_path(sharding, shapes)
+
+
+def init_weights(m: dict, seed: int, devices=None):
     """The served model's weights, drawn again from its seed in the dtype
-    the configuration serves (``m["dtype"]``)."""
+    the configuration serves (``m["dtype"]``), each split over ``devices``
+    (default: the first device) as it is made, so no device ever holds a
+    whole model that only several can.  Threefry draws are partitionable,
+    so the values do not depend on the placement."""
     dtype = jnp.dtype(m["dtype"])
-    return jax.jit(functools.partial(_init, m, dtype=dtype))(
-        jax.random.PRNGKey(seed))
+    init = functools.partial(_init, m, dtype=dtype)
+    key = jax.random.PRNGKey(seed)
+    out = _placement(jax.eval_shape(init, key), devices or jax.devices()[:1])
+    return jax.jit(init, out_shardings=out)(key)
 
 
 def _fp8(x):
@@ -182,10 +211,3 @@ def forward(m: dict, w, tokens, pos, mask, rows, *, quantize=None):
     with jax.default_matmul_precision("highest"):
         fn = _compiled(tuple(sorted(m.items())), quantize)
         return np.asarray(fn(w, tokens, pos, mask, rows), np.float64)
-
-
-def entropy(logits: np.ndarray) -> np.ndarray:
-    """Shannon entropy (nats) over the last axis, in float64."""
-    z = logits - logits.max(-1, keepdims=True)
-    logp = z - np.log(np.exp(z).sum(-1, keepdims=True))
-    return -(np.exp(logp) * logp).sum(-1)

@@ -5,7 +5,10 @@
 From the root of a checkout.  The cell names a configuration
 (``bench/configs/<name>.json``) and a traffic mix
 (``bench/traffic/<name>.json``); its limits for ``correct`` are in
-``bench/limits/<cell>.json``.  Set-up (``setup_s``) runs from process
+``bench/limits/<cell>.json``.  The configuration names the mesh its
+engine is served on (``"mesh"``, default ``"1x1"``), whose devices must be
+the cell's ``chips``, and its plain reference
+(``bench/references/<name>.py``).  Set-up (``setup_s``) runs from process
 start to the first request of the window: the compile cache, the engine
 with its weights, and the warm-up jobs.  The window runs jobs back to back
 for ``--seconds``.  With ``--trace 0`` the result carries the cell's
@@ -14,8 +17,9 @@ traced whole and the result carries the per-layer metrics read from it.
 Then the served model is freed and a sample of the finished requests is
 checked against the plain reference (``bench/correct.py``).
 
-Without a TPU, or with fewer chips than the cell asks for, the run exits
-non-zero and prints no result.  The last line of standard output is the
+Without a TPU, with fewer chips than the cell asks for, or with a cell
+whose chips are not its mesh's, the run exits non-zero and prints no
+result.  The last line of standard output is the
 JSON result; the numbers compared, each beside its limit, are the last
 lines of standard error and the last key of the result.
 """
@@ -48,9 +52,17 @@ def load_json(path: Path) -> dict:
         return json.load(f)
 
 
+def load_module(path: Path, name: str):
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
 def load_cell(root: Path, workload: str, bench: Path = BENCH) -> dict:
-    """The cell, its configuration, mix, limits and metric entries — all
-    found by the names in ``BENCHMARK.json``."""
+    """The cell, its configuration, mix, limits, reference module and
+    metric entries — all found by the names in ``BENCHMARK.json`` and the
+    configuration file."""
     spec = load_json(root / "BENCHMARK.json")
     cells = {w["name"]: w for w in spec["workloads"]}
     if workload not in cells:
@@ -58,12 +70,24 @@ def load_cell(root: Path, workload: str, bench: Path = BENCH) -> dict:
                          f"cells: {sorted(cells)}")
     cell = cells[workload]
 
+    cfg = load_json(bench / "configs" / f"{cell['config']}.json")
+    if harness.mesh_devices(cfg) != cell["chips"]:
+        raise SystemExit(f"{workload}: {cell['chips']} chip(s), but its "
+                         f"configuration's mesh {cfg.get('mesh', '1x1')} "
+                         f"spans {harness.mesh_devices(cfg)}")
+    ref = bench / "references" / f"{cfg.get('reference')}.py"
+    if not ref.exists():
+        raise SystemExit(f"{workload}: configuration {cell['config']} names "
+                         f"reference {cfg.get('reference')!r}, but there is "
+                         f"no {ref}")
+
     def mine(metric):
         return workload in metric.get("workloads", [workload])
 
     return {
         "cell": cell,
-        "cfg": load_json(bench / "configs" / f"{cell['config']}.json"),
+        "cfg": cfg,
+        "reference": load_module(ref, f"bench_reference_{ref.stem}"),
         "mix": load_json(bench / "traffic" / f"{cell['traffic']}.json"),
         "limits": load_json(bench / "limits" / f"{workload}.json"),
         "end_to_end": [m for m in spec["end_to_end"] if mine(m)],
@@ -76,11 +100,7 @@ def metric_reader(name: str, bench: Path = BENCH):
     path = bench / "metrics" / f"{name}.py"
     if not path.exists():
         return None
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{name}",
-                                                  path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return load_module(path, f"bench_metric_{name}").read
 
 
 def read_metrics(entries: list[dict], rec: dict, bench: Path = BENCH) -> dict:
@@ -215,19 +235,15 @@ def run(args, root: Path, bench: Path = BENCH, *,
     else:
         metrics = read_metrics(c["end_to_end"], rec, bench)
 
-    # the reference runs once the served model's state is freed
+    # the reference runs once the served model's state is freed, over the
+    # cell's chips
     del engine
     gc.collect()
-    from bench import reference as ref
-
     t_ref = time.perf_counter()
-    weights = {"generator": ref.init_weights(cfg["generator"]["model"],
-                                             cfg["generator"]["weights_seed"])}
-    if cfg["monitor"] == "proxy":
-        weights["proxy"] = ref.init_weights(cfg["proxy"]["model"],
-                                            cfg["proxy"]["weights_seed"])
+    ref = c["reference"]
+    weights = cmp.reference_weights(ref, cfg, used)
     checked = [finished[i] for i in cmp.sample(results, args.seed)]
-    nums = cmp.compare(cfg, mix, checked, weights,
+    nums = cmp.compare(cfg, mix, checked, ref, weights,
                        margin=limits["eat_var_rel"])["program"]
     print(f"reference check: {len(checked)} requests, "
           f"{sum(len(r['reasoning_tokens']) for _, r in checked)} served "

@@ -29,6 +29,7 @@ TINY_MIX = {"prompt_len": {"min": 4, "max": 16, "median": 8, "sigma": 0.5},
 
 def tiny_cfg(monitor: str = "self", delta: float = 1e9) -> dict:
     cfg = {"source": "test", "reduced": [], "assumed": {},
+           "reference": "qwen_dense",
            "generator": {"arch": "tiny", "weights_seed": 0,
                          "model": TINY_MODEL},
            "proxy": None, "monitor": monitor,
@@ -50,9 +51,10 @@ def write_json(path: Path, obj) -> None:
 
 def make_tiny_bench(tmp_path: Path):
     """(root, bench dir) holding a BENCHMARK.json with two tiny cells,
-    their files, and the real per-layer metric readers."""
+    their files, and the real per-layer metric readers and references."""
     bench = tmp_path / "bench"
-    shutil.copytree(ROOT / "bench" / "metrics", bench / "metrics")
+    for sub in ("metrics", "references"):
+        shutil.copytree(ROOT / "bench" / sub, bench / sub)
     spec = json.loads((ROOT / "BENCHMARK.json").read_text())
     spec["configs"] = []
     spec["workloads"] = []

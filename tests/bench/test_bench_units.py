@@ -161,14 +161,14 @@ def _ev(name, start, dur, stats=()):
                            stats=list(stats))
 
 
-def _pd(host, device):
+def _pd(host, device, chips=1):
     line = lambda name, evs: SimpleNamespace(name=name, events=evs)  # noqa: E731
     return SimpleNamespace(planes=[
-        SimpleNamespace(name="/host:CPU", lines=[line("main", host)]),
-        SimpleNamespace(name="/device:TPU:0",
+        SimpleNamespace(name="/host:CPU", lines=[line("main", host)])] + [
+        SimpleNamespace(name=f"/device:TPU:{i}",
                         lines=[line("XLA Ops", device),
-                               line("XLA Modules", [_ev("mod", 0, 10**9)])]),
-    ])
+                               line("XLA Modules", [_ev("mod", 0, 10**9)])])
+        for i in range(chips)])
 
 
 def test_trace_reduction_on_a_hand_made_trace():
@@ -197,6 +197,67 @@ def test_trace_reduction_on_a_hand_made_trace():
     ops = dict(red["device_ops"])
     assert ops["fusion"] == pytest.approx(200e-6)       # leaves only
     assert "while" not in ops                           # encloses fusion.9
+    assert red["collective_s"] == 0
+
+
+#: one job's window of 1 ms: 0.6 ms of device work on each chip, of which
+#: 0.15 ms is exchanges between chips
+SHARDED_OPS = [
+    _ev("%fusion.1 = bf16[48,4096] fusion(...)", 1_000_000, 200_000),
+    _ev("%all-reduce.7 = bf16[48,4096] all-reduce(...)", 1_200_000, 100_000),
+    _ev("%fusion.2 = bf16[48,4096] fusion(...)", 1_300_000, 250_000),
+    _ev("%all-gather-start.3 = bf16[48,256] all-gather-start(...)",
+        1_550_000, 30_000),
+    _ev("%all-gather-done.3 = bf16[48,1024] all-gather-done(...)",
+        1_580_000, 20_000),
+    # outside the window: not counted
+    _ev("%all-reduce.8 = bf16[48,4096] all-reduce(...)", 2_100_000, 50_000)]
+
+
+def _sharded_rec(chips: int) -> dict:
+    c = bench_run.load_cell(bench_run.BENCH.parent,
+                            "qwen3-1.7b.self-eat.reasoning")
+    red = reduce_trace(_pd([_ev(WINDOW_SPAN, 1_000_000, 1_000_000)],
+                           SHARDED_OPS, chips))
+    return {"cfg": c["cfg"], "mix": c["mix"], "trace": red,
+            "peaks": peaks_for("TPU v5 lite"),
+            "traced": {"job": {"prompt_len": np.asarray([64, 100])},
+                       "results": [{"n_reasoning": 129}] * 2}}
+
+
+def test_a_share_of_the_peak_is_taken_over_every_chip():
+    """The same work in the same window on four chips reads a quarter of
+    the share it reads on one."""
+    one, four = _sharded_rec(1), _sharded_rec(4)
+    assert four["trace"]["n_devices"] == 4
+    for key in ("busy_s", "collective_s"):
+        assert four["trace"][key] == pytest.approx(one["trace"][key]), key
+    for key in ("device_ops", "idle_gaps"):       # means over chips too
+        assert dict(four["trace"][key]) == pytest.approx(
+            dict(one["trace"][key])), key
+    mfu = bench_run.metric_reader("decode_mfu")
+    assert mfu(four) == pytest.approx(mfu(one) / 4)
+    # by hand: two requests' model FLOPs over the window and four peaks
+    m, a = one["cfg"]["generator"]["model"], one["mix"]["answer_len"]
+    want = sum(flops.emitted_token_flops(m, P, 129, a) for P in (64, 100))
+    assert mfu(four) == pytest.approx(100 * want / (1e-3 * 4 * 197e12))
+    fl, by = 1e12, 1e9
+    s1, _ = flops.roofline_share(fl, by, 1e-3, one["peaks"])
+    s4, _ = flops.roofline_share(fl, by, 1e-3, one["peaks"], chips=4)
+    assert s4 == pytest.approx(s1 / 4)
+
+
+@pytest.mark.parametrize("chips", [1, 4])
+def test_collective_share_reads_the_exchanges_between_chips(chips):
+    rec = _sharded_rec(chips)
+    red = rec["trace"]
+    assert red["collective_s"] == pytest.approx(150e-6)     # per chip
+    assert red["busy_s"] == pytest.approx(600e-6)
+    got = bench_run.metric_reader("collective_share")(rec)
+    if chips == 1:
+        assert got is None              # one chip exchanges nothing
+    else:
+        assert got == pytest.approx(25.0)
 
 
 # ---------------------------------------------------------------- reference
@@ -217,9 +278,13 @@ def _tiny_model(tiny_bench) -> dict:
     return cfg["generator"]["model"]
 
 
-def test_reference_draws_the_served_weights_from_the_seed(tiny_bench):
-    from bench import reference as ref
+def _reference(tiny_bench):
+    root, bench = tiny_bench
+    return bench_run.load_cell(root, "tiny.self.mix", bench)["reference"]
 
+
+def test_reference_draws_the_served_weights_from_the_seed(tiny_bench):
+    ref = _reference(tiny_bench)
     tiny = _tiny_model(tiny_bench)
     _, params = _program_tiny()
     w = ref.init_weights(tiny, 0)
@@ -237,9 +302,9 @@ def test_reference_draws_the_served_weights_from_the_seed(tiny_bench):
 def test_reference_agrees_with_the_served_prefill(tiny_bench):
     import jax.numpy as jnp
 
-    from bench import reference as ref
     from repro.serving.cache import alloc_cache
 
+    ref = _reference(tiny_bench)
     tiny = _tiny_model(tiny_bench)
     model, params = _program_tiny()
     toks = np.asarray([[21, 33, 40, 25, 63, 19, 50, 22]], np.int32)
@@ -259,17 +324,17 @@ def test_float8_control_reads_far_above_the_program(tiny_bench):
     place) must read well above what the served path reads, on the same
     requests: the comparison can tell a lower precision apart."""
     from bench import correct, harness
-    from bench import reference as ref
 
     root, bench = tiny_bench
     c = bench_run.load_cell(root, "tiny.self.mix", bench)
-    cfg, mix = c["cfg"], c["mix"]
+    cfg, mix, ref = c["cfg"], c["mix"], c["reference"]
     engine = harness.build_engine(cfg, mix)
     job = traffic.make_job(mix, 64, 99, 0)
     res = harness.serve_job(engine, cfg, mix, job, 5)
     finished = [(bench_run.unpadded(job, i), r) for i, r in enumerate(res)]
-    w = {"generator": ref.init_weights(cfg["generator"]["model"], 0)}
-    nums = correct.compare(cfg, mix, finished, w, margin=1e-3, control=True)
+    w = correct.reference_weights(ref, cfg, None)
+    nums = correct.compare(cfg, mix, finished, ref, w, margin=1e-3,
+                           control=True)
     prog, ctrl = nums["program"], nums["control"]
     assert prog["answer_gap"] <= 1e-3 and prog["eat_var_rel"] <= 1e-3
     assert ctrl["eat_var_rel"] > 10 * max(prog["eat_var_rel"], 1e-6)
@@ -316,6 +381,7 @@ def test_reduction_of_a_recorded_chip_trace():
     from jax.profiler import ProfileData
 
     from bench.peaks import peaks_for
+    from repro.serving.telemetry import ServeResults, Telemetry
 
     path = Path(__file__).parent / "fixtures" / "self_eat_job.xplane.pb.gz"
     red = reduce_trace(ProfileData.from_serialized_xspace(
@@ -329,11 +395,15 @@ def test_reduction_of_a_recorded_chip_trace():
     assert 0 < red["kernel_s"]["paged_attention"] < red["busy_s"]
     c = bench_run.load_cell(bench_run.BENCH.parent,
                             "qwen3-1.7b.self-eat.reasoning")
+    # the same job's telemetry, served again (test_telemetry_metrics)
+    tel = Telemetry.load(json.loads(
+        (path.parent / "self_eat_job.telemetry.json").read_text()))
     rec = {"cfg": c["cfg"], "mix": {**c["mix"], "budget": 34},
            "peaks": peaks_for("TPU v5 lite"), "trace": red, "compiles": 0,
            "values": {},
            "traced": {"job": {"prompt_len": np.asarray(FIXTURE_PROMPTS)},
-                      "results": [{"n_reasoning": 34}] * 16}}
+                      "results": ServeResults([{"n_reasoning": 34}] * 16,
+                                              tel)}}
     got = {k: v["value"] for k, v in
            bench_run.read_metrics(c["per_layer"], rec).items()}
     assert set(got) == {m["name"] for m in c["per_layer"]}
